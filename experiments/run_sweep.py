@@ -10,7 +10,9 @@ def main():
     multi = "--multi-pod" in sys.argv
     pod = "pod2" if multi else "pod1"
     out = os.path.join(os.path.dirname(__file__), "dryrun")
-    env = {**os.environ, "PYTHONPATH": "src"}
+    # children are CPU-only dry-runs: never let one reach for a chip
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if multi:
         # pod2 is the shardability proof (the roofline table is single-pod
         # per the assignment): compile at opt level 0 to fit wall-clock.
@@ -26,7 +28,7 @@ def main():
                    "--shape", shape, "--out", out]
             if multi:
                 cmd.append("--multi-pod")
-            r = subprocess.run(cmd, env=env, cwd="/root/repo",
+            r = subprocess.run(cmd, env=env, cwd=repo,
                                capture_output=True, text=True, timeout=7200)
             status = "ok" if r.returncode == 0 else "FAIL"
             print(f"{arch} {shape} {pod}: {status} {time.time()-t0:.0f}s", flush=True)

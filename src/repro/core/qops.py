@@ -53,8 +53,8 @@ __all__ = ["qmatmul", "qbmm", "qembed", "qconv", "qcontract", "qrelu",
 def _chunk_count(k: int, chunk: int) -> int:
     """Number of accumulator chunks covering a contraction of length k.
 
-    Always ``ceil(k / chunk)``: ``_pt_dot`` zero-pads K up to an exact
-    multiple, so no divisor search is needed.  (The previous
+    Always ``ceil(k / chunk)``: ``_pt_dot`` lets the last chunk run
+    short, so no divisor search is needed.  (The previous
     ``while k % n: n += 1`` walk was O(k) for prime K and could silently
     shrink chunks to size 1 — e.g. k=509, chunk=128 used to yield 509
     chunks of one element.)
@@ -68,33 +68,24 @@ def _pt_dot(am: jnp.ndarray, bm: jnp.ndarray, nbatch: int, nchunk: int) -> jnp.n
     """Integer dot, per-tensor scale: a (*B, M, K) x b (*B, N, K) -> (*B, M, N) int32->f32.
 
     ``nchunk`` > 1 splits K so each int32 accumulator only ever sums
-    ceil(K/nchunk) int8 x int8 products; partials are combined in f32
-    (emulating periodic accumulator flushes).  K is zero-padded up to
-    nchunk * ceil(K/nchunk) — zero mantissas add nothing, so the split is
-    exact for any K, including primes.
+    ceil(K/nchunk) int8 x int8 products; partials are combined in f32 in
+    chunk order (emulating periodic accumulator flushes).  The last chunk
+    may be shorter — the same partial sums as zero-padding K up to
+    nchunk * ceil(K/nchunk), so the split is exact for any K, including
+    primes.  (One dot per chunk slice: the TPU compiler takes over a
+    minute on the equivalent reshape into a chunk-batched dot.)
     """
     k = am.shape[-1]
-    if nchunk == 1:
-        acc = lax.dot_general(
-            am, bm,
-            (((am.ndim - 1,), (bm.ndim - 1,)),
-             (tuple(range(nbatch)), tuple(range(nbatch)))),
-            preferred_element_type=jnp.int32)
-        return acc.astype(jnp.float32)
+    dims = (((am.ndim - 1,), (bm.ndim - 1,)),
+            (tuple(range(nbatch)), tuple(range(nbatch))))
     kc = -(-k // nchunk)
-    pad = nchunk * kc - k
-    if pad:
-        widths = [(0, 0)] * (am.ndim - 1) + [(0, pad)]
-        am = jnp.pad(am, widths)
-        bm = jnp.pad(bm, widths)
-    a4 = jnp.moveaxis(am.reshape(*am.shape[:-1], nchunk, kc), -2, nbatch)
-    b4 = jnp.moveaxis(bm.reshape(*bm.shape[:-1], nchunk, kc), -2, nbatch)
-    acc = lax.dot_general(
-        a4, b4,
-        (((a4.ndim - 1,), (b4.ndim - 1,)),
-         (tuple(range(nbatch + 1)), tuple(range(nbatch + 1)))),
-        preferred_element_type=jnp.int32)
-    return acc.astype(jnp.float32).sum(axis=nbatch)
+    acc = None
+    for lo in range(0, k, kc):
+        part = lax.dot_general(am[..., lo:lo + kc], bm[..., lo:lo + kc], dims,
+                               preferred_element_type=jnp.int32)
+        part = part.astype(jnp.float32)
+        acc = part if acc is None else acc + part
+    return acc
 
 
 def _blk_dot(aq: BFP, bq: BFP, nbatch: int) -> jnp.ndarray:

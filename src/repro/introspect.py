@@ -3,7 +3,7 @@
 The qflow dataflow (docs/DATAFLOW.md) claims to remove redundant
 quantize passes between layers.  This module makes that claim measurable:
 :func:`count_quantize_ops` traces a function and walks its jaxpr —
-recursing through pjit / scan / while / cond / remat / custom_vjp call
+recursing through jit / scan / while / cond / remat / custom_vjp call
 primitives — counting every call of the named quantization routines
 (``core.bfp.quantize``; ``fx_quantize`` and the norm layers route through
 it too, so one number covers GEMM and norm quantization alike).
@@ -24,13 +24,14 @@ from __future__ import annotations
 from typing import Callable, Dict, Iterable
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = ["count_quantize_ops", "count_weight_quantize_ops",
            "count_cache_quantize_ops", "count_named_calls",
            "health_summary",
            "QUANTIZE_NAMES", "WEIGHT_QUANTIZE_NAMES", "CACHE_QUANTIZE_NAMES"]
 
-# pjit names of the quantization entry points (jitted functions keep their
+# jit call names of the quantization entry points (jitted functions keep their
 # Python function name as the jaxpr call name).  Weight-operand
 # quantizations route through the separately-named ``quantize_weight``
 # wrapper (core.bfp) — same mapping, distinct jaxpr name — so the
@@ -52,21 +53,16 @@ def _jaxprs_of(eqn) -> Iterable[tuple]:
     """Yield (sub_jaxpr, trip_multiplier) for every jaxpr-valued param."""
     length = eqn.params.get("length", 1) if eqn.primitive.name == "scan" else 1
     for v in eqn.params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
-            yield v.jaxpr, length
-        elif isinstance(v, jax.core.Jaxpr):
-            yield v, length
-        elif isinstance(v, (tuple, list)):
-            for w in v:
-                if isinstance(w, jax.core.ClosedJaxpr):
-                    yield w.jaxpr, length
-                elif isinstance(w, jax.core.Jaxpr):
-                    yield w, length
+        for w in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(w, ClosedJaxpr):
+                yield w.jaxpr, length
+            elif isinstance(w, Jaxpr):
+                yield w, length
 
 
 def _walk(jaxpr, names, mult: int, counts: Dict[str, int]) -> None:
     for eqn in jaxpr.eqns:
-        name = eqn.params.get("name", "") if eqn.primitive.name == "pjit" else ""
+        name = eqn.params.get("name", "") if eqn.primitive.name == "jit" else ""
         if name in names:
             counts[name] = counts.get(name, 0) + mult
             continue                      # a counted call is a leaf
@@ -76,7 +72,7 @@ def _walk(jaxpr, names, mult: int, counts: Dict[str, int]) -> None:
 
 def count_named_calls(fn: Callable, *args, names=QUANTIZE_NAMES,
                       **kwargs) -> Dict[str, int]:
-    """Trace ``fn(*args, **kwargs)`` and count named pjit calls, weighted by
+    """Trace ``fn(*args, **kwargs)`` and count named jit calls, weighted by
     scan trip counts.  Returns {name: executions} plus a "total" key."""
     jaxpr = jax.make_jaxpr(fn)(*args, **kwargs)
     counts: Dict[str, int] = {}

@@ -21,36 +21,17 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
+
+from .tile import quantize_tile
 
 __all__ = ["bfp_quantize_pallas"]
 
-_BASE_SHIFT = 17
-
 
 def _kernel(x_ref, rand_ref, e_ref, out_ref):
-    x = x_ref[...]
-    rand = rand_ref[...]
-    e_shared = e_ref[...]                                    # (block_rows, 1)
-    b = lax.bitcast_convert_type(x, jnp.uint32)
-    sign = (b >> 31).astype(jnp.int32)
-    bexp = ((b >> 23) & 0xFF).astype(jnp.int32)
-    frac = b & jnp.uint32(0x7FFFFF)
-    mant24 = jnp.where(bexp > 0, frac | jnp.uint32(1 << 23), frac)
-    eff = jnp.maximum(bexp, 1)
-
-    s = (e_shared - eff) + _BASE_SHIFT
-    s31 = jnp.minimum(s, 31).astype(jnp.uint32)
-    base = jnp.where(s < 32, mant24 >> s31, jnp.uint32(0))
-    m_lo = mant24 & ((jnp.uint32(1) << s31) - jnp.uint32(1))
-    left = jnp.clip(32 - s, 0, 31).astype(jnp.uint32)
-    over = jnp.clip(s - 32, 0, 31).astype(jnp.uint32)
-    thr = jnp.where(s <= 31, m_lo << left,
-                    jnp.where(s == 32, mant24, mant24 >> over))
-    up = (rand < thr) & (s > 0)
-    mag = jnp.minimum(base + up.astype(jnp.uint32), jnp.uint32(127)).astype(jnp.int32)
-    out_ref[...] = jnp.where(sign == 1, -mag, mag).astype(jnp.int8)
+    # e_ref: (block_rows, 1) shared exponents, broadcast along the row
+    out_ref[...] = quantize_tile(x_ref[...], rand_ref[...], e_ref[...], 7,
+                                 stochastic=True)
 
 
 @partial(jax.jit, static_argnames=("block_rows", "interpret"))

@@ -74,12 +74,14 @@ from ..core.bfp import (BFP, PER_TENSOR, QuantConfig, pow2, rounding_bits,
                         storage_dtype)
 from ..core.bfp import quantize as bfp_quantize
 from ..runtime import fault_injection as _fi
+from ..runtime.sharding import current_mesh
 from . import autotune, ref
 from .bfp_quant import bfp_quantize_pallas
 from .fused_linear import (fused_gemm_epi_pallas, fused_ii_pt_pallas,
                            fused_qi_pt_pallas, fused_qq_blk_pallas,
                            fused_qq_pt_pallas, gemm_epi_ref)
 from .int8_matmul import int8_matmul_pallas
+from .tile import round_up
 
 __all__ = [
     "FUSED", "UNFUSED", "JNP", "Decision", "plan_contract",
@@ -99,9 +101,21 @@ FUSED = "fused"
 UNFUSED = "unfused"
 JNP = "jnp"
 
-# Conservative residency budget for one fused-kernel instance (the chip has
-# ~16 MB VMEM; leave headroom for double buffering and the compiler).
+# Residency budget for one fused-kernel instance.  Mosaic's default scoped
+# VMEM limit on TPU v5e is 16 MiB; the estimates count double buffering
+# and in-kernel temporaries, and the rest is headroom for the compiler.
 DEFAULT_VMEM_BUDGET = 12 * 1024 * 1024
+
+# GSPMD does not partition a Mosaic kernel: inside a jit over a multi-
+# device mesh (bound by ``runtime.sharding.use_rules``) a kernel call fails
+# to lower, so compiled plans there take the jnp path instead.
+UNPARTITIONED = "Mosaic kernels are not partitioned over a multi-device mesh"
+
+
+def _on_multi_device_mesh() -> bool:
+    mesh = current_mesh()
+    return mesh is not None and mesh.size > 1
+
 
 _LANE = 128       # last-dim tile multiple
 _INT8_SUBLANE = 32
@@ -286,15 +300,11 @@ def _jnp_block_matmul(am: jnp.ndarray, bmant: jnp.ndarray, ea, eb,
 # geometry helpers
 # ---------------------------------------------------------------------------
 
-def _round_up(x: int, mult: int) -> int:
-    return -(-x // mult) * mult
-
-
 def _pad2(x: jnp.ndarray, rm: int, cm: int, value=0) -> jnp.ndarray:
     """Zero-pad the last two dims up to multiples (rm, cm); exact through
     quantize (0 -> mantissa 0) and GEMM (0 contributes nothing)."""
-    pr = _round_up(x.shape[-2], rm) - x.shape[-2]
-    pc = _round_up(x.shape[-1], cm) - x.shape[-1]
+    pr = round_up(x.shape[-2], rm) - x.shape[-2]
+    pc = round_up(x.shape[-1], cm) - x.shape[-1]
     if pr or pc:
         pad = [(0, 0)] * (x.ndim - 2) + [(0, pr), (0, pc)]
         x = jnp.pad(x, pad, constant_values=value)
@@ -304,25 +314,33 @@ def _pad2(x: jnp.ndarray, rm: int, cm: int, value=0) -> jnp.ndarray:
 def _vmem_bytes(kind: str, bm: int, k: int, n: int, nb: int) -> int:
     """Residency estimate for one fused-kernel instance, in bytes.
 
-    Row-strip (per-program, double-buffered) + resident b-side blocks.
+    Pallas double-buffers every blocked operand, the resident b-side blocks
+    included (a constant index map still gets two buffers), so both the
+    row strip and the resident blocks count twice.  On top come the
+    in-kernel temporaries: the quantizer's f32-wide working copy of the
+    largest tile it quantizes and the int32 accumulator of the strip.
     kind: "qq" f32 a+rand / f32 b+rand + both mantissa outputs;
           "qq_blk" adds the int32 exponent blocks;
           "qi" drops b's f32/rand (int8 resident); "ii" drops a's too.
     """
     y = 4 * bm * n
+    acc = 4 * bm * n
     if kind in ("qq", "qq_blk"):
         a_strip = (4 + 4 + 1) * bm * k + y
         b_res = (4 + 4 + 1) * n * k
+        tmp = 4 * max(bm, n) * k
         if kind == "qq_blk":
             a_strip += 4 * bm * nb
             b_res += 4 * n * nb
     elif kind == "qi":
         a_strip = (4 + 4 + 1) * bm * k + y
         b_res = 1 * n * k
+        tmp = 4 * bm * k
     else:  # "ii" / "pp": both operands arrive as int8 mantissas
         a_strip = 1 * bm * k + y
         b_res = 1 * n * k
-    return 2 * a_strip + b_res
+        tmp = 0
+    return 2 * (a_strip + b_res) + tmp + acc
 
 
 def bytes_moved(path: str, m: int, k: int, n: int, *, stochastic: bool = True,
@@ -609,9 +627,9 @@ def _make_attn_bench(gs: int, t: int, d: int, cfg: QuantConfig, s: int,
 
     def bench(bq: int) -> float:
         rng = np.random.RandomState(0)
-        gsp = _round_up(max(gs, 1), bq)
-        tp = _round_up(t, bt)
-        dp = _round_up(d, _LANE)
+        gsp = round_up(max(gs, 1), bq)
+        tp = round_up(t, bt)
+        dp = round_up(d, _LANE)
         qm = jnp.asarray(rng.randint(-127, 128, (gsp, dp), np.int8))
         km = jnp.asarray(rng.randint(-127, 128, (tp, dp), np.int8))
         vm = jnp.asarray(rng.randint(-127, 128, (tp, dp), np.int8))
@@ -666,18 +684,20 @@ def plan_attention(op: str, gs: int, t: int, d: int, cfg: QuantConfig, *,
         return decide(JNP, "fused attention is per-tensor only")
     if kernel_mode == "auto" and interpret:
         return decide(JNP, f"auto keeps the scan path on backend={backend}")
+    if not interpret and _on_multi_device_mesh():
+        return decide(JNP, UNPARTITIONED)
     bt = attn_block_t(t)
-    tp = _round_up(t, bt)
-    dp = _round_up(d, _LANE)
+    tp = round_up(t, bt)
+    dp = round_up(d, _LANE)
     if op in ("attn_bwd", "attn_decode"):
-        gsp = _round_up(gs, _INT8_SUBLANE)
+        gsp = round_up(gs, _INT8_SUBLANE)
         if _attn_vmem_bytes(op, 0, gsp, tp, dp, bt,
                             cfg.stochastic) <= vmem_budget:
             return decide(FUSED, "fused attention fits VMEM budget", bt=bt)
         return decide(JNP, f"no residency fits vmem_budget={vmem_budget}")
 
     def fits(bq):
-        return _attn_vmem_bytes(op, bq, _round_up(gs, bq), tp, dp, bt,
+        return _attn_vmem_bytes(op, bq, round_up(gs, bq), tp, dp, bt,
                                 cfg.stochastic) <= vmem_budget
 
     key = autotune.shape_key(f"attn_{kind}", gs, d, t, cfg.bits, 0, backend)
@@ -733,6 +753,8 @@ def plan_contract(op: str, m: int, k: int, n: int, cfg: QuantConfig, *,
         return decide(JNP, "pp needs per-tensor scales on both operands")
     if kernel_mode == "auto" and interpret:
         return decide(JNP, f"auto keeps the jnp oracle on backend={backend}")
+    if not interpret and _on_multi_device_mesh():
+        return decide(JNP, UNPARTITIONED)
     if cfg.block == PER_TENSOR and k > accum_chunk:
         # The jnp path emulates periodic hardware accumulator flushes by
         # chunking K; neither kernel path reproduces that flush.
@@ -742,8 +764,8 @@ def plan_contract(op: str, m: int, k: int, n: int, cfg: QuantConfig, *,
         return decide(JNP, f"K={k} overflows the int32 accumulator")
 
     blk = cfg.block
-    kp = _round_up(k, _LANE if blk == PER_TENSOR else (_LANE * blk) // math.gcd(_LANE, blk))
-    np_ = _round_up(n, _LANE)
+    kp = round_up(k, _LANE if blk == PER_TENSOR else (_LANE * blk) // math.gcd(_LANE, blk))
+    np_ = round_up(n, _LANE)
     nb = 0 if blk == PER_TENSOR else kp // blk
     vkind = "qq_blk" if (kind == "qq" and blk != PER_TENSOR) else kind
 
@@ -752,7 +774,7 @@ def plan_contract(op: str, m: int, k: int, n: int, cfg: QuantConfig, *,
     # walks the freshly-quantized side (N rows) while the pre-quantized int8
     # mantissas (M rows) stay resident.
     strip_rows = n if kind == "iq" else m
-    res_cols = _round_up(m, _LANE) if kind == "iq" else np_
+    res_cols = round_up(m, _LANE) if kind == "iq" else np_
     vmem_kind = "qi" if vkind == "iq" else vkind
     fused_block = None
     if kernel_mode in ("auto", "fused"):
@@ -810,11 +832,11 @@ def _make_bench(vkind: str, m: int, k: int, n: int, cfg: QuantConfig,
 
     def bench(bm: int) -> float:
         rng = np.random.RandomState(0)
-        mp = _round_up(max(m, 1), bm)
+        mp = round_up(max(m, 1), bm)
         blk = cfg.block
-        kp = _round_up(k, _LANE if blk == PER_TENSOR
+        kp = round_up(k, _LANE if blk == PER_TENSOR
                        else (_LANE * blk) // math.gcd(_LANE, blk))
-        np_ = _round_up(n, _LANE)
+        np_ = round_up(n, _LANE)
         a = jnp.asarray(rng.randn(mp, kp).astype(np.float32))
         b = jnp.asarray(rng.randn(np_, kp).astype(np.float32))
         ra = jnp.asarray(rng.randint(0, 2**32, (mp, kp), np.uint32))
@@ -989,7 +1011,7 @@ def contract_qq(a: jnp.ndarray, b: jnp.ndarray, cfg: QuantConfig,
     ea = ref.max_biased_exp_blocks_ref(a, blk)    # (*B, M, K/blk)
     eb = ref.max_biased_exp_blocks_ref(b, blk)
     kmult = (_LANE * blk) // math.gcd(_LANE, blk)
-    nbp = _round_up(k, kmult) // blk
+    nbp = round_up(k, kmult) // blk
     # Padded blocks/rows get biased exponent 1: their (zero) mantissas scale
     # to exactly 0, so the padding is invisible in the f32 combine.
 
@@ -1317,8 +1339,10 @@ def plan_norm_gemm(op: str, m: int, k: int, n: int, cfg: QuantConfig, *,
         return decide(JNP, f"bits={cfg.bits} (kernels are int8-only)")
     if kernel_mode == "auto" and interpret:
         return decide(JNP, f"auto keeps the unfused seam on backend={backend}")
-    kp = _round_up(k, _LANE)
-    np_ = _round_up(n, _LANE)
+    if not interpret and _on_multi_device_mesh():
+        return decide(JNP, UNPARTITIONED)
+    kp = round_up(k, _LANE)
+    np_ = round_up(n, _LANE)
 
     def fits(bm):
         return _norm_gemm_vmem_bytes(bm, kp, np_, cfg.stochastic,
@@ -1346,9 +1370,9 @@ def _make_norm_gemm_bench(m: int, k: int, n: int, cfg: QuantConfig,
 
     def bench(bm: int) -> float:
         rng = np.random.RandomState(0)
-        mp = _round_up(max(m, 1), bm)
-        kp = _round_up(k, _LANE)
-        np_ = _round_up(n, _LANE)
+        mp = round_up(max(m, 1), bm)
+        kp = round_up(k, _LANE)
+        np_ = round_up(n, _LANE)
         x = jnp.asarray(rng.randn(mp, kp).astype(np.float32))
         rin = jnp.asarray(rng.randint(0, 2 ** 32, (mp, kp), np.uint32))
         rout = jnp.asarray(rng.randint(0, 2 ** 32, (mp, kp), np.uint32))
@@ -1385,9 +1409,9 @@ def run_norm_gemm(x, rand_in, rand_out, gm, se_g, beta_m, se_b, w_m, se_w,
     from . import fused_chain as fc
 
     m, k = x.shape[-2], x.shape[-1]
-    kp = _round_up(k, _LANE)
+    kp = round_up(k, _LANE)
     nn = w_m.shape[0]
-    np_ = _round_up(nn, _LANE)
+    np_ = round_up(nn, _LANE)
     xp = _pad2(x, 1, kp)
     gm_p = _pad2(gm, 1, kp)
     beta_p = None if beta_m is None else _pad2(beta_m, 1, kp)
@@ -1468,6 +1492,8 @@ def plan_epilogue(op: str, m: int, k: int, n: int, cfg: QuantConfig, *,
         return decide(JNP, "epilogue chains are per-tensor only")
     if kernel_mode == "auto" and interpret:
         return decide(JNP, f"auto keeps the jnp oracle on backend={backend}")
+    if not interpret and _on_multi_device_mesh():
+        return decide(JNP, UNPARTITIONED)
     if k > accum_chunk:
         return decide(JNP, f"K={k} > accum_chunk={accum_chunk} "
                            "(flush emulation stays on jnp)")
@@ -1476,8 +1502,8 @@ def plan_epilogue(op: str, m: int, k: int, n: int, cfg: QuantConfig, *,
     glu = (act or "").endswith("_glu")
     if glu and (n % (2 * _LANE) or n % 2):
         return decide(JNP, "glu halves must be lane-aligned")
-    kp = _round_up(k, _LANE)
-    np_ = _round_up(n, _LANE)
+    kp = round_up(k, _LANE)
+    np_ = round_up(n, _LANE)
     n_out = n // 2 if glu else np_
     base = "qq" if kind == "qq" else ("qi" if kind == "qi" else "ii")
 
@@ -1507,9 +1533,9 @@ def _make_epi_bench(kind: str, m: int, k: int, n: int, cfg: QuantConfig,
 
     def bench(bm: int) -> float:
         rng = np.random.RandomState(0)
-        mp = _round_up(max(m, 1), bm)
-        kp = _round_up(k, _LANE)
-        np_ = _round_up(n, _LANE)
+        mp = round_up(max(m, 1), bm)
+        kp = round_up(k, _LANE)
+        np_ = round_up(n, _LANE)
         n_out = n // 2 if (act or "").endswith("_glu") else np_
         sr = cfg.stochastic
         if kind == "ii":
@@ -1715,6 +1741,10 @@ def plan_decode_block(op: str, b: int, d: int, n_ff: int, t: int, hq: int,
         return decide(JNP, f"bits={cfg.bits} (kernels are int8-only)")
     if kernel_mode == "auto" and interpret:
         return decide(JNP, f"auto keeps the per-op path on backend={backend}")
+    if not interpret:
+        # Mosaic refuses the megakernel's in-kernel head reshapes and its
+        # dynamic_update_slice row append; interpret mode runs it.
+        return decide(JNP, "decode block does not lower through Mosaic")
     if _decode_block_vmem_bytes(b, d, n_ff, t, hq, hkv, dh) > vmem_budget:
         return decide(JNP, f"no residency fits vmem_budget={vmem_budget}")
     if op in _disabled_ops:

@@ -65,8 +65,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .dispatch import _round_up
-from .fused_linear import _pow2_f32, _quantize_tile, _scale_exp
+from .tile import eff_exp, pow2_f32, quantize_tile, round_up, scale_exp
 
 __all__ = [
     "fused_attn_fwd_pallas",
@@ -78,12 +77,6 @@ __all__ = [
 ]
 
 _NEG = -1e30  # matches models.attention._NEG
-
-
-def _eff_exp(x):
-    """Effective biased exponent of f32 ``x`` (sub-normals clamp to 1)."""
-    b = lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.maximum(((b >> 23) & 0xFF).astype(jnp.int32), 1)
 
 
 def _qk_dot(qm, km_j):
@@ -133,8 +126,8 @@ def _fwd_blocks(qm, kblk, vblk, rpblk, eq, ek, ev, qpos, kv_len, lo, hi, *,
     their scores mask to −1e30, so m, l and acc pass through unchanged).
     """
     bq = qm.shape[0]
-    sc_qk = _pow2_f32(_scale_exp(eq, p) + _scale_exp(ek, p))
-    sev = _scale_exp(ev, p)
+    sc_qk = pow2_f32(scale_exp(eq, p) + scale_exp(ek, p))
+    sev = scale_exp(ev, p)
 
     def body(j, carry):
         m, l, acc = carry
@@ -149,11 +142,11 @@ def _fwd_blocks(qm, kblk, vblk, rpblk, eq, ek, ev, qpos, kv_len, lo, hi, *,
         # one shared exponent per query row per block: QuantConfig(bits,
         # block=bt) semantics, entirely tile-local.  The per-row scale
         # factors out of the integer PV dot as a per-output-row epilogue.
-        e_row = _eff_exp(pt).max(axis=-1, keepdims=True)
-        ph = _quantize_tile(pt, None if rpblk is None else rpblk(j), e_row,
+        e_row = eff_exp(pt).max(axis=-1, keepdims=True)
+        ph = quantize_tile(pt, None if rpblk is None else rpblk(j), e_row,
                             p, stochastic)
         pv = _pv_dot(ph, vblk(j)).astype(jnp.float32)
-        acc = acc * alpha + pv * _pow2_f32(_scale_exp(e_row, p) + sev)
+        acc = acc * alpha + pv * pow2_f32(scale_exp(e_row, p) + sev)
         return m_new, l * alpha + pt.sum(axis=-1, keepdims=True), acc
 
     init = (jnp.full((bq, 1), _NEG, jnp.float32),
@@ -181,28 +174,28 @@ def _bwd_block(j, qm, gm, km_j, vm_j, m, l, delta, rs_j, rp_j, eq, ek, ev,
     gs = qm.shape[0]
     kpos = j * bt + lax.broadcasted_iota(jnp.int32, (gs, bt), 1)
     mask = _block_mask(qpos, kpos, kv_len, causal, window) & row_ok
-    sc_qk = _pow2_f32(_scale_exp(eq, p) + _scale_exp(ek, p))
+    sc_qk = pow2_f32(scale_exp(eq, p) + scale_exp(ek, p))
     sf = _qk_dot(qm, km_j).astype(jnp.float32) * sc_qk
     sf = jnp.where(mask, sf, _NEG)
     pt = jnp.where(mask, jnp.exp(sf - m), 0.0)
     pn = pt / jnp.maximum(l, 1e-30)
     # dV = P̂ᵀ Ĝ — pn's scale rides the contraction rows, so one shared
     # exponent per tile (a scalar) is what factors out of the int32 dot.
-    e_pn = _eff_exp(pn).max()
-    pn_h = _quantize_tile(pn, rp_j, e_pn, p, stochastic)
-    dv_j = _tn_dot(pn_h, gm).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(e_pn, p) + _scale_exp(eg, p))
+    e_pn = eff_exp(pn).max()
+    pn_h = quantize_tile(pn, rp_j, e_pn, p, stochastic)
+    dv_j = _tn_dot(pn_h, gm).astype(jnp.float32) * pow2_f32(
+        scale_exp(e_pn, p) + scale_exp(eg, p))
     # dP = Ĝ V̂ᵀ ; dS = P ∘ (dP − δ)
-    dp = _qk_dot(gm, vm_j).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(eg, p) + _scale_exp(ev, p))
+    dp = _qk_dot(gm, vm_j).astype(jnp.float32) * pow2_f32(
+        scale_exp(eg, p) + scale_exp(ev, p))
     ds = pn * (dp - delta)
-    e_ds = _eff_exp(ds).max()
-    ds_h = _quantize_tile(ds, rs_j, e_ds, p, stochastic)
-    sc_ds = _scale_exp(e_ds, p)
-    dq_c = _pv_dot(ds_h, km_j).astype(jnp.float32) * _pow2_f32(
-        sc_ds + _scale_exp(ek, p))
-    dk_j = _tn_dot(ds_h, qm).astype(jnp.float32) * _pow2_f32(
-        sc_ds + _scale_exp(eq, p))
+    e_ds = eff_exp(ds).max()
+    ds_h = quantize_tile(ds, rs_j, e_ds, p, stochastic)
+    sc_ds = scale_exp(e_ds, p)
+    dq_c = _pv_dot(ds_h, km_j).astype(jnp.float32) * pow2_f32(
+        sc_ds + scale_exp(ek, p))
+    dk_j = _tn_dot(ds_h, qm).astype(jnp.float32) * pow2_f32(
+        sc_ds + scale_exp(eq, p))
     return dq_c, dk_j, dv_j
 
 
@@ -216,21 +209,21 @@ def _decode_core(qm, km, vm, ek_rows, ev_rows, rp, eq, qpos, kv_len, *,
     band) — the in-kernel fusion of ``qcache_qk`` + softmax + ``qcache_pv``.
     """
     gs, t = qm.shape[0], km.shape[0]
-    sek = _scale_exp(ek_rows, p).reshape(1, t)
-    sev = _scale_exp(ev_rows, p).reshape(1, t)
+    sek = scale_exp(ek_rows, p).reshape(1, t)
+    sev = scale_exp(ev_rows, p).reshape(1, t)
     kpos = lax.broadcasted_iota(jnp.int32, (gs, t), 1)
     mask = _block_mask(qpos, kpos, kv_len, causal, window)
-    sf = _qk_dot(qm, km).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(eq, p) + sek)
+    sf = _qk_dot(qm, km).astype(jnp.float32) * pow2_f32(
+        scale_exp(eq, p) + sek)
     sf = jnp.where(mask, sf, _NEG)
     mrow = sf.max(axis=-1, keepdims=True)
     pe = jnp.exp(sf - mrow)
     pn = jnp.where(mask, pe / pe.sum(axis=-1, keepdims=True), 0.0)
-    p2 = pn * _pow2_f32(sev)                    # exact ×2^e fold
-    e_row = _eff_exp(p2).max(axis=-1, keepdims=True)
-    ph = _quantize_tile(p2, rp, e_row, p, rp is not None)
+    p2 = pn * pow2_f32(sev)                    # exact ×2^e fold
+    e_row = eff_exp(p2).max(axis=-1, keepdims=True)
+    ph = quantize_tile(p2, rp, e_row, p, rp is not None)
     y = _pv_dot(ph, vm).astype(jnp.float32)
-    return y * _pow2_f32(_scale_exp(e_row, p))  # V runs at unit ref scale
+    return y * pow2_f32(scale_exp(e_row, p))  # V runs at unit ref scale
 
 
 def _strip_bounds(i, bq, s, q_off, kv_len, *, bt, causal, window, contig):
@@ -569,7 +562,7 @@ def attn_fwd(qm, km, vm, rp, eq, ek, ev, q_off, kv_len, *, p, s, bq, bt,
     """
     gs, d = qm.shape[-2], qm.shape[-1]
     t = km.shape[-2]
-    gsp, tp, dp = _round_up(gs, bq), _round_up(t, bt), _round_up(d, 128)
+    gsp, tp, dp = round_up(gs, bq), round_up(t, bt), round_up(d, 128)
     kv = jnp.minimum(jnp.asarray(kv_len, jnp.int32), t)
     qo = jnp.asarray(q_off, jnp.int32)
     qm = _pad_rows(qm, gsp, dp)
@@ -606,7 +599,7 @@ def attn_bwd(qm, gm, km, vm, m, l, delta, rs, rp2, eq, ek, ev, eg, q_off,
     gs, d = qm.shape[-2], qm.shape[-1]
     t = km.shape[-2]
     # the Q side stays whole-resident: pad rows to the int8 sublane pack
-    gsp, tp, dp = _round_up(gs, 32), _round_up(t, bt), _round_up(d, 128)
+    gsp, tp, dp = round_up(gs, 32), round_up(t, bt), round_up(d, 128)
     kv = jnp.minimum(jnp.asarray(kv_len, jnp.int32), t)
     qo = jnp.asarray(q_off, jnp.int32)
     qm, gm = _pad_rows(qm, gsp, dp), _pad_rows(gm, gsp, dp)
@@ -647,7 +640,7 @@ def attn_decode(qm, km, vm, ek_rows, ev_rows, rp, eq, q_off, kv_len, *,
     """
     gs, d = qm.shape[-2], qm.shape[-1]
     t = km.shape[-2]
-    gsp, tp, dp = _round_up(gs, 32), _round_up(t, 32), _round_up(d, 128)
+    gsp, tp, dp = round_up(gs, 32), round_up(t, 32), round_up(d, 128)
     kv = jnp.minimum(jnp.asarray(kv_len, jnp.int32), t)
     qo = jnp.asarray(q_off, jnp.int32)
     qm = _pad_rows(qm, gsp, dp)

@@ -59,8 +59,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .fused_attention import _decode_core, _eff_exp
-from .fused_linear import _int8_dot, _pow2_f32, _quantize_tile, _scale_exp
+from .fused_attention import _decode_core
+from .tile import eff_exp, int8_dot, pow2_f32, quantize_tile, scale_exp
 
 __all__ = [
     "decode_block_ref",
@@ -154,11 +154,11 @@ def _int_rsqrt(vm, ev):
 def _row_quantize(x, rand, p, mask=None):
     """Per-row shared-exponent int8 quantize of an f32 tile.
     Returns (mantissas int8, biased row exponents (R, 1) int32)."""
-    e = _eff_exp(x)
+    e = eff_exp(x)
     if mask is not None:
         e = jnp.where(mask, e, 1)
     e_row = e.max(axis=-1, keepdims=True)
-    return _quantize_tile(x, rand, e_row, p, rand is not None), e_row
+    return quantize_tile(x, rand, e_row, p, rand is not None), e_row
 
 
 def _norm_rows_core(x, rand_in, rand_out, gm, se_g, bm_, se_b, *, n, p,
@@ -179,7 +179,7 @@ def _norm_rows_core(x, rand_in, rand_out, gm, se_g, bm_, se_b, *, n, p,
 
     # 1. per-row input quantize to 7 magnitude bits (the c7 step of qnorm)
     c, e_in = _row_quantize(x, rand_in, 7, mask)
-    sc = _scale_exp(e_in, 7)                       # (R, 1) value scale of c
+    sc = scale_exp(e_in, 7)                       # (R, 1) value scale of c
     ci = c.astype(jnp.int32)
 
     if center:
@@ -251,8 +251,8 @@ def _norm_gemm_core(x, rand_in, rand_out, gm, se_g, bm_, se_b, w_m, se_w, *,
     xq, se_row, c, sc, r, e_r = _norm_rows_core(
         x, rand_in, rand_out, gm, se_g, bm_, se_b, n=n, p=p,
         eps_m=eps_m, eps_e=eps_e, center=center, stochastic=rand_out is not None)
-    acc = _int8_dot(xq, w_m)
-    y = acc.astype(jnp.float32) * _pow2_f32(se_row + se_w)
+    acc = int8_dot(xq, w_m)
+    y = acc.astype(jnp.float32) * pow2_f32(se_row + se_w)
     return y, xq, _pack_meta(se_row, sc, r, e_r), c
 
 
@@ -405,7 +405,7 @@ def _decode_block_core(x, wqkv_m, se_qkv, wo_m, se_o, wgu_m, se_gu, wd_m,
     xq1, se1, *_ = _norm_rows_core(
         x, None, None, g1m, se_g1, None, None, n=n_d, p=p,
         eps_m=eps_m, eps_e=eps_e, center=False, stochastic=False)
-    qkv = _int8_dot(xq1, wqkv_m).astype(jnp.float32) * _pow2_f32(se1 + se_qkv)
+    qkv = int8_dot(xq1, wqkv_m).astype(jnp.float32) * pow2_f32(se1 + se_qkv)
     nq, nk = hq * dh, hkv * dh
     q = qkv[:, :nq].reshape(b, hq, dh)
     k = qkv[:, nq:nq + nk].reshape(b, hkv, dh)
@@ -433,8 +433,8 @@ def _decode_block_core(x, wqkv_m, se_qkv, wo_m, se_o, wgu_m, se_gu, wd_m,
             ve_f = lax.dynamic_update_slice(
                 ve[bi, h], ev_new[bi * hkv + h][None, :], (pos, 0))
             qg = q[bi, h * gs:(h + 1) * gs]
-            eq = _eff_exp(qg).max()
-            qm = _quantize_tile(qg, None, eq, p, False)
+            eq = eff_exp(qg).max()
+            qm = quantize_tile(qg, None, eq, p, False)
             attn.append(_decode_core(
                 qm, km_f, vm_f, ke_f[:, 0], ve_f[:, 0], None, eq, qpos,
                 pos + 1, p=p, causal=True, window=window))
@@ -442,19 +442,19 @@ def _decode_block_core(x, wqkv_m, se_qkv, wo_m, se_o, wgu_m, se_gu, wd_m,
 
     # --- out projection + residual ---
     aq, ea = _row_quantize(y, None, p)
-    o = _int8_dot(aq, wo_m).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(ea, p) + se_o)
+    o = int8_dot(aq, wo_m).astype(jnp.float32) * pow2_f32(
+        scale_exp(ea, p) + se_o)
     h2 = x + o
 
     # --- norm 2 -> gated MLP (merged gate|up GEMM, silu-GLU epilogue) ---
     xq2, se2, *_ = _norm_rows_core(
         h2, None, None, g2m, se_g2, None, None, n=n_d, p=p,
         eps_m=eps_m, eps_e=eps_e, center=False, stochastic=False)
-    gu = _int8_dot(xq2, wgu_m).astype(jnp.float32) * _pow2_f32(se2 + se_gu)
+    gu = int8_dot(xq2, wgu_m).astype(jnp.float32) * pow2_f32(se2 + se_gu)
     act = jax.nn.silu(gu[:, :n_ff]) * gu[:, n_ff:]
     mq, em = _row_quantize(act, None, p)
-    dn = _int8_dot(mq, wd_m).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(em, p) + se_d)
+    dn = int8_dot(mq, wd_m).astype(jnp.float32) * pow2_f32(
+        scale_exp(em, p) + se_d)
     return h2 + dn, k_new, ek_new, v_new, ev_new
 
 

@@ -51,6 +51,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tile import eff_exp, int8_dot, pow2_f32, quantize_tile, scale_exp
+
 __all__ = [
     "fused_qq_pt_pallas",
     "fused_qi_pt_pallas",
@@ -59,66 +61,6 @@ __all__ = [
     "fused_gemm_epi_pallas",
     "gemm_epi_ref",
 ]
-
-_F32_EXP_BIAS = 127
-_F32_MANT_BITS = 23
-
-
-def _scale_exp(e_biased, p):
-    """Unbiased exponent of a p-magnitude-bit BFP scale (cf. core.bfp)."""
-    return e_biased - _F32_EXP_BIAS - _F32_MANT_BITS + (24 - p)
-
-
-def _pow2_f32(e):
-    """Exact 2^e for int32 e, flushing e < -126 to 0 (mirrors core.bfp.pow2)."""
-    e = e.astype(jnp.int32) if hasattr(e, "astype") else jnp.int32(e)
-    e1 = jnp.clip(e, -126, 127)
-    f = lax.bitcast_convert_type(
-        ((e1 + _F32_EXP_BIAS) << _F32_MANT_BITS).astype(jnp.uint32), jnp.float32)
-    return jnp.where(e < -126, jnp.float32(0.0), f)
-
-
-def _quantize_tile(x, rand, e_shared, p, stochastic):
-    """Linear fixed-point mapping of a VMEM-resident f32 tile to int8.
-
-    Bit-identical to ``ref.bfp_quantize_ref`` / ``core.bfp.quantize`` given
-    the same random bits: unpack the IEEE-754 pattern, shift-align to the
-    shared exponent, threshold-compare round (stochastic against ``rand``,
-    or half-up when ``stochastic`` is False — then ``rand`` may be None),
-    clamp the 2^p - 1 rounding overflow of the e_max element, re-apply the
-    sign.
-    """
-    base_shift = 24 - p
-    b = lax.bitcast_convert_type(x, jnp.uint32)
-    sign = (b >> 31).astype(jnp.int32)
-    bexp = ((b >> 23) & 0xFF).astype(jnp.int32)
-    frac = b & jnp.uint32(0x7FFFFF)
-    mant24 = jnp.where(bexp > 0, frac | jnp.uint32(1 << 23), frac)
-    eff = jnp.maximum(bexp, 1)
-
-    s = (e_shared - eff) + base_shift
-    s31 = jnp.minimum(s, 31).astype(jnp.uint32)
-    base = jnp.where(s < 32, mant24 >> s31, jnp.uint32(0))
-    m_lo = mant24 & ((jnp.uint32(1) << s31) - jnp.uint32(1))
-    left = jnp.clip(32 - s, 0, 31).astype(jnp.uint32)
-    over = jnp.clip(s - 32, 0, 31).astype(jnp.uint32)
-    thr = jnp.where(s <= 31, m_lo << left,
-                    jnp.where(s == 32, mant24, mant24 >> over))
-    if stochastic:
-        up = (rand < thr) & (s > 0)
-    else:
-        # Half-up: dropped fraction >= 1/2  <=>  lifted threshold >= 2^31.
-        up = (thr >= jnp.uint32(0x80000000)) & (s > 0)
-    mag = jnp.minimum(base + up.astype(jnp.uint32),
-                      jnp.uint32((1 << p) - 1)).astype(jnp.int32)
-    return jnp.where(sign == 1, -mag, mag).astype(jnp.int8)
-
-
-def _int8_dot(am, bm):
-    """(bm, K) int8 x (N, K) int8 -> (bm, N) int32 on the MXU."""
-    return lax.dot_general(am, bm, (((1,), (1,)), ((), ())),
-                           preferred_element_type=jnp.int32)
-
 
 # ---------------------------------------------------------------------------
 # per-tensor scale kernels (the paper's mode)
@@ -144,18 +86,18 @@ def _qq_pt_kernel(es_ref, *refs, p, stochastic, emit_residuals):
 
     @pl.when(pl.program_id(0) == 0)
     def _():
-        bm_ref[...] = _quantize_tile(
+        bm_ref[...] = quantize_tile(
             b_ref[...], None if rb_ref is None else rb_ref[...], eb,
             p, stochastic)
 
-    am = _quantize_tile(a_ref[...],
+    am = quantize_tile(a_ref[...],
                         None if ra_ref is None else ra_ref[...], ea,
                         p, stochastic)
     if am_ref is not None:
         am_ref[...] = am
-    acc = _int8_dot(am, bm_ref[...])
-    y_ref[...] = acc.astype(jnp.float32) * _pow2_f32(
-        _scale_exp(ea, p) + _scale_exp(eb, p))
+    acc = int8_dot(am, bm_ref[...])
+    y_ref[...] = acc.astype(jnp.float32) * pow2_f32(
+        scale_exp(ea, p) + scale_exp(eb, p))
 
 
 def _qi_pt_kernel(es_ref, *refs, pa, pb, stochastic):
@@ -166,21 +108,21 @@ def _qi_pt_kernel(es_ref, *refs, pa, pb, stochastic):
         ra_ref = None
     ea = es_ref[0]
     eb = es_ref[1]
-    am = _quantize_tile(a_ref[...],
+    am = quantize_tile(a_ref[...],
                         None if ra_ref is None else ra_ref[...], ea,
                         pa, stochastic)
     am_ref[...] = am
-    acc = _int8_dot(am, b_ref[...])
-    y_ref[...] = acc.astype(jnp.float32) * _pow2_f32(
-        _scale_exp(ea, pa) + _scale_exp(eb, pb))
+    acc = int8_dot(am, b_ref[...])
+    y_ref[...] = acc.astype(jnp.float32) * pow2_f32(
+        scale_exp(ea, pa) + scale_exp(eb, pb))
 
 
 def _ii_pt_kernel(es_ref, a_ref, b_ref, y_ref, *, pa, pb):
     ea = es_ref[0]
     eb = es_ref[1]
-    acc = _int8_dot(a_ref[...], b_ref[...])
-    y_ref[...] = acc.astype(jnp.float32) * _pow2_f32(
-        _scale_exp(ea, pa) + _scale_exp(eb, pb))
+    acc = int8_dot(a_ref[...], b_ref[...])
+    y_ref[...] = acc.astype(jnp.float32) * pow2_f32(
+        scale_exp(ea, pa) + scale_exp(eb, pb))
 
 
 @partial(jax.jit, static_argnames=("p", "bm", "stochastic", "interpret",
@@ -321,10 +263,10 @@ def _blk_combine(am, bq, sea, seb, blk, out_shape):
     def body(bi, acc):
         a_blk = lax.dynamic_slice_in_dim(am, bi * blk, blk, axis=1)
         b_blk = lax.dynamic_slice_in_dim(bq, bi * blk, blk, axis=1)
-        part = _int8_dot(a_blk, b_blk)
+        part = int8_dot(a_blk, b_blk)
         sa = lax.dynamic_slice_in_dim(sea, bi, 1, axis=1)        # (bm, 1)
         sb = lax.dynamic_slice_in_dim(seb, bi, 1, axis=1)        # (N, 1)
-        return acc + part.astype(jnp.float32) * _pow2_f32(sa + sb.reshape(1, -1))
+        return acc + part.astype(jnp.float32) * pow2_f32(sa + sb.reshape(1, -1))
 
     return lax.fori_loop(0, nb, body, jnp.zeros(out_shape, jnp.float32))
 
@@ -349,17 +291,17 @@ def _qq_blk_kernel(*refs, p, blk, stochastic, emit_residuals):
 
     @pl.when(pl.program_id(0) == 0)
     def _():
-        bm_ref[...] = _quantize_tile(
+        bm_ref[...] = quantize_tile(
             b_ref[...], None if rb_ref is None else rb_ref[...],
             _bcast_blk(eb, blk), p, stochastic)
 
-    am = _quantize_tile(a_ref[...],
+    am = quantize_tile(a_ref[...],
                         None if ra_ref is None else ra_ref[...],
                         _bcast_blk(ea, blk), p, stochastic)
     if am_ref is not None:
         am_ref[...] = am
-    y_ref[...] = _blk_combine(am, bm_ref[...], _scale_exp(ea, p),
-                              _scale_exp(eb, p), blk, y_ref.shape)
+    y_ref[...] = _blk_combine(am, bm_ref[...], scale_exp(ea, p),
+                              scale_exp(eb, p), blk, y_ref.shape)
 
 
 @partial(jax.jit, static_argnames=("p", "blk", "bm", "stochastic",
@@ -430,12 +372,6 @@ _EPI_ACTS = (None, "relu", "gelu", "silu_glu", "gelu_glu")
 _EPI_META_LANES = 128
 
 
-def _eff_exp_f32(x):
-    """Effective biased exponent of f32 x (sub-normals clamp to 1)."""
-    b = lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.maximum(((b >> 23) & 0xFF).astype(jnp.int32), 1)
-
-
 def epi_apply(y, bias, act, n_out):
     """The f32 epilogue on a GEMM output tile: bias add, then activation.
     ``*_glu`` acts gate the left half against the right half (the merged
@@ -500,7 +436,7 @@ def _gemm_epi_kernel(es_ref, *refs, kind, p, pa, pb, stochastic, act,
     if kind == "qq":
         @pl.when(first)
         def _():
-            bm_ref[...] = _quantize_tile(
+            bm_ref[...] = quantize_tile(
                 b_ref[...], None if rb_ref is None else rb_ref[...], eb,
                 pb, stochastic)
         bmant = bm_ref[...]
@@ -509,13 +445,13 @@ def _gemm_epi_kernel(es_ref, *refs, kind, p, pa, pb, stochastic, act,
     if kind == "ii":
         am = a_ref[...]
     else:
-        am = _quantize_tile(a_ref[...],
+        am = quantize_tile(a_ref[...],
                             None if ra_ref is None else ra_ref[...], ea,
                             pa, stochastic)
         if am_ref is not None:
             am_ref[...] = am
-    ylin = _int8_dot(am, bmant).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(ea, pa) + _scale_exp(eb, pb))
+    ylin = int8_dot(am, bmant).astype(jnp.float32) * pow2_f32(
+        scale_exp(ea, pa) + scale_exp(eb, pb))
     if bias_ref is not None:
         ylin = ylin + bias_ref[...]
     if ylin_ref is not None:
@@ -543,11 +479,11 @@ def _gemm_epi_kernel(es_ref, *refs, kind, p, pa, pb, stochastic, act,
 
     @pl.when(ph == 1)
     def _():
-        e_out = _eff_exp_f32(amax_ref[0, 0])
-        yo_ref[...] = _quantize_tile(
+        e_out = eff_exp(amax_ref[0, 0])
+        yo_ref[...] = quantize_tile(
             y, None if rq_ref is None else rq_ref[...], e_out, qp,
             stochastic)
-        emeta_ref[...] = jnp.full((1, _EPI_META_LANES), e_out, jnp.int32)
+        emeta_ref[...] = jnp.broadcast_to(e_out, (1, _EPI_META_LANES))
 
 
 @partial(jax.jit, static_argnames=("kind", "p", "pa", "pb", "bm",
@@ -668,17 +604,17 @@ def gemm_epi_ref(a, ra, b, rb, bias, rq, ea, eb, *, kind="qq", p=7, pa=None,
     ea = jnp.asarray(ea, jnp.int32)
     eb = jnp.asarray(eb, jnp.int32)
     if kind == "qq":
-        bmant = _quantize_tile(b, rb if stochastic else None, eb, pb,
+        bmant = quantize_tile(b, rb if stochastic else None, eb, pb,
                                stochastic)
     else:
         bmant = b
     if kind == "ii":
         am = a
     else:
-        am = _quantize_tile(a, ra if stochastic else None, ea, pa,
+        am = quantize_tile(a, ra if stochastic else None, ea, pa,
                             stochastic)
-    ylin = _int8_dot(am, bmant).astype(jnp.float32) * _pow2_f32(
-        _scale_exp(ea, pa) + _scale_exp(eb, pb))
+    ylin = int8_dot(am, bmant).astype(jnp.float32) * pow2_f32(
+        scale_exp(ea, pa) + scale_exp(eb, pb))
     if bias is not None:
         ylin = ylin + bias
     y = epi_apply(ylin, None, act, n_out)
@@ -686,8 +622,8 @@ def gemm_epi_ref(a, ra, b, rb, bias, rq, ea, eb, *, kind="qq", p=7, pa=None,
         av = jnp.abs(y)
         if m_true is not None:
             av = jnp.where(jnp.arange(a.shape[0])[:, None] < m_true, av, 0.0)
-        e_out = _eff_exp_f32(av.max())
-        ym = _quantize_tile(y, rq if stochastic else None, e_out, qp,
+        e_out = eff_exp(av.max())
+        ym = quantize_tile(y, rq if stochastic else None, e_out, qp,
                             stochastic)
         out = [ym, jnp.full((1, _EPI_META_LANES), e_out, jnp.int32)]
     else:

@@ -7,8 +7,10 @@ GEMM kernel).  Routing between them, the fused pipeline in
 ``kernels.dispatch`` — model code goes through ``core.qops``, which plans
 via dispatch; call these wrappers directly only for sweeps and benchmarks.
 
-``use_pallas`` selects the kernel path (interpret=True on CPU so the same
-code validates here and compiles for TPU).  Note ``quantize_op`` exposes
+``use_pallas`` selects the kernel path.  ``interpret`` defaults to the
+backend, as ``kernels.dispatch`` does: compiled on a TPU, interpret mode
+everywhere else (the same code validates on CPU and compiles for TPU).
+Note ``quantize_op`` exposes
 *per-row-block* scale granularity (one exponent per ``block_rows`` rows),
 which differs from ``core.bfp`` per-tensor / per-K-block modes; per-tensor
 (``per_tensor=True``) matches ``core.bfp.quantize`` bit-for-bit given the
@@ -31,6 +33,10 @@ from .int8_matmul import int8_matmul_pallas
 __all__ = ["quantize_op", "int8_matmul_op"]
 
 
+def _interpret(interpret: Optional[bool]) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
 def _pad_to(x: jnp.ndarray, mult_rows: int, mult_cols: int) -> jnp.ndarray:
     m, n = x.shape
     pm = (-m) % mult_rows
@@ -43,7 +49,7 @@ def _pad_to(x: jnp.ndarray, mult_rows: int, mult_cols: int) -> jnp.ndarray:
 @partial(jax.jit, static_argnames=("per_tensor", "use_pallas", "interpret",
                                    "block_rows"))
 def quantize_op(x: jnp.ndarray, key: jax.Array, *, per_tensor: bool = True,
-                use_pallas: bool = True, interpret: bool = True,
+                use_pallas: bool = True, interpret: Optional[bool] = None,
                 block_rows: int = 8) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Quantize a 2-D f32 tensor to (int8 mantissas, per-row-block biased
     exponent). per_tensor=True broadcasts one shared exponent everywhere
@@ -65,15 +71,15 @@ def quantize_op(x: jnp.ndarray, key: jax.Array, *, per_tensor: bool = True,
     rp = _pad_to(rand, block_rows, 128)
     ep = jnp.pad(e_rows, (0, xp.shape[0] - m), constant_values=1)[:, None]
     mant = bfp_quantize_pallas(xp, rp, ep, block_rows=block_rows,
-                               interpret=interpret)
+                               interpret=_interpret(interpret))
     return mant[:m, :n], e_rows
 
 
 @partial(jax.jit, static_argnames=("use_pallas", "interpret", "bm", "bn", "bk"))
 def int8_matmul_op(a_m: jnp.ndarray, b_m: jnp.ndarray, ea: jnp.ndarray,
                    eb: jnp.ndarray, *, use_pallas: bool = True,
-                   interpret: bool = True, bm: int = 128, bn: int = 128,
-                   bk: int = 128) -> jnp.ndarray:
+                   interpret: Optional[bool] = None, bm: int = 128,
+                   bn: int = 128, bk: int = 128) -> jnp.ndarray:
     """(M,K) x (K,N) int8 mantissas with scalar biased exponents -> f32.
 
     Exponents add (integer add); the combined scale is one f32 multiply on
@@ -89,5 +95,5 @@ def int8_matmul_op(a_m: jnp.ndarray, b_m: jnp.ndarray, ea: jnp.ndarray,
     ap = _pad_to(a_m, bm, bk)
     bp = _pad_to(b_m, bk, bn)
     out = int8_matmul_pallas(ap, bp, scale, bm=bm, bn=bn, bk=bk,
-                             interpret=interpret)
+                             interpret=_interpret(interpret))
     return out[:m, :n]

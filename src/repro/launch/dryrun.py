@@ -1,14 +1,17 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"    # placeholder devices, never a chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract memory / cost / roofline terms.
 
-The two lines above MUST precede any jax import: jax locks the device
-count at first init, and the dry-run needs 512 placeholder CPU devices so
-``jax.make_mesh`` can build the 2x16x16 production mesh. (Only this module
-sets the flag — tests and benches see the real single device.)
+The lines above MUST precede any jax import: jax locks the device count
+and the platform at first init, and the dry-run needs 512 placeholder CPU
+devices so ``jax.make_mesh`` can build the 2x16x16 production mesh.  It
+pins itself to the CPU platform, so on a machine with an accelerator it
+never takes the chip from another process.  (Only this module sets the
+flag — tests and benches see the real single device.)
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2_0_5b \

@@ -35,6 +35,7 @@ from ..core.policy import FLOAT32, PAPER_INT8
 from ..kernels import dispatch
 from ..models import (get_cache_layout, get_cache_page_spec,
                       get_draft_support, get_model)
+from .compile_cache import enable_compile_cache
 from .steps import (cache_template, make_decode_step, make_prefill_step,
                     quantize_serving_params)
 
@@ -673,6 +674,7 @@ def main(argv=None):
                          "(docs/ROBUSTNESS.md §Serving resilience); "
                          "output stays bitwise identical")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     try:
         if (args.speculate or args.draft_layers) and not args.engine:
             raise ServeConfigError(

@@ -7,6 +7,10 @@ feeding the straggler monitor.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2_0_5b --smoke \
         --steps 50 --batch 8 --seq 64 --policy int8 --ckpt-dir /tmp/ckpt
+
+``--mesh DATA,MODEL`` trains over a (data, model) mesh of that many local
+devices (default 1,1): the state is placed with the production sharding
+rules, the batch split over ``data``.
 """
 
 from __future__ import annotations
@@ -14,11 +18,12 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..checkpoint import CheckpointManager
 from ..configs import ARCH_IDS, get_config, get_smoke_config
@@ -31,8 +36,10 @@ from ..optim import sgd_init, wsd_schedule
 from ..runtime import fault_injection as finj
 from ..runtime.fault_tolerance import StragglerMonitor
 from ..runtime.sharding import DEFAULT_RULES, use_rules
-from .mesh import make_local_mesh
-from .steps import TrainHyper, make_float_train_step, make_train_step
+from .compile_cache import enable_compile_cache
+from .mesh import make_local_mesh, parse_mesh_shape
+from .steps import (TrainHyper, make_float_train_step, make_train_step,
+                    params_shardings, state_shardings)
 from .supervisor import GuardConfig, TrainSupervisor
 
 POLICIES = {"int8": PAPER_INT8, "float32": FLOAT32,
@@ -76,7 +83,8 @@ def train(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
           use_wsd: bool = False, quiet: bool = False, qflow: bool = False,
           qweights: bool = False, health: bool = False,
           guard: Optional[GuardConfig] = None, fault_plan=None,
-          sim_hosts: int = 1, supervisor: Optional[TrainSupervisor] = None):
+          sim_hosts: int = 1, supervisor: Optional[TrainSupervisor] = None,
+          mesh_shape: Tuple[int, int] = (1, 1)):
     """Train loop.  ``health=True`` computes the per-step numeric-health
     report and runs it through a :class:`TrainSupervisor` — tripped guards
     roll the run back to the last committed state with bounded retries
@@ -85,7 +93,9 @@ def train(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
     corruption after a chosen committed step and/or a simulated dead host
     driving the Heartbeat -> re-mesh -> restore path.  Returns
     ``(losses, state)``; with a supervisor attached, its ``events`` list
-    is the recovery telemetry."""
+    is the recovery telemetry.  ``mesh_shape`` = (data, model) devices;
+    each step's wall time (first one includes compilation) is kept in
+    ``train.last_step_seconds``."""
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
     policy = POLICIES[policy_name]
     if qflow and policy.enabled:
@@ -140,17 +150,31 @@ def train(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
         if not quiet:
             print(f"resumed from step {start_step}")
 
-    losses = []
-    faults_done: set = set()
     # a concrete (possibly 1x1) mesh: logical_constraint needs one to turn
     # PartitionSpecs into NamedShardings (bare specs require a mesh context
-    # manager, which jitted step functions don't have)
-    with use_rules(DEFAULT_RULES, make_local_mesh()):
+    # manager, which jitted step functions don't have).  State and batch
+    # are placed on it even at 1x1: the step's outputs carry the mesh in
+    # their types, and inputs without it would compile the step twice.
+    mesh = make_local_mesh(*mesh_shape)
+    if policy.enabled:
+        state = jax.device_put(
+            state, state_shardings(cfg, policy, mesh, DEFAULT_RULES))
+    else:
+        psh = params_shardings(cfg, mesh, DEFAULT_RULES)
+        state = jax.device_put(state, (psh, type(state[1])(
+            psh, NamedSharding(mesh, P()))))
+    batch_sh = NamedSharding(mesh, DEFAULT_RULES.spec(("batch",)))
+
+    losses = []
+    step_seconds = []
+    faults_done: set = set()
+    with use_rules(DEFAULT_RULES, mesh):
         step = start_step
         while step < steps:
             t0 = time.time()
             hb = ds.batch_for_step(step)
-            batch_j = {k: jnp.asarray(v) for k, v in hb.items()}
+            batch_j = jax.device_put(
+                {k: jnp.asarray(v) for k, v in hb.items()}, batch_sh)
             if cfg.family == "vlm":
                 batch_j["patch_embeds"] = jax.random.normal(
                     jax.random.fold_in(key, step),
@@ -190,10 +214,12 @@ def train(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
                     if offset:
                         ds = dataclasses.replace(ds, seed=seed + offset)
                     del losses[max(step - start_step, 0):]
+                    del step_seconds[max(step - start_step, 0):]
                     continue
 
             state = new_state
             losses.append(float(loss))
+            step_seconds.append(time.time() - t0)
             if sup is not None:
                 sup.commit(step, state)
             if mgr and (step + 1) % ckpt_every == 0:
@@ -214,6 +240,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
                               f"from step {restore_step}")
                     if restore_step is not None and restore_step != step + 1:
                         del losses[max(restore_step - start_step, 0):]
+                        del step_seconds[max(restore_step - start_step, 0):]
                         step = restore_step
                         continue
 
@@ -231,12 +258,15 @@ def train(arch: str, *, smoke: bool = True, steps: int = 50, batch: int = 8,
             mgr.wait()
     if sup is not None:
         train.last_supervisor = sup
+    train.last_step_seconds = step_seconds
     return losses, state
 
 
-# telemetry handle for callers that don't construct their own supervisor
-# (tools/chaos_smoke.py): the supervisor of the most recent train() call.
+# telemetry handles of the most recent train() call: its supervisor, for
+# callers that don't construct their own (tools/chaos_smoke.py), and the
+# wall seconds of each committed step (chip_smoke.py).
 train.last_supervisor = None
+train.last_step_seconds = []
 
 
 def main():
@@ -253,6 +283,9 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--wsd", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--mesh", type=parse_mesh_shape, default=(1, 1),
+                    metavar="DATA,MODEL",
+                    help="train over a (data, model) mesh of local devices")
     ap.add_argument("--qflow", action="store_true",
                     help="quantized activations as the inter-layer currency "
                          "(docs/DATAFLOW.md); no-op for --policy float32")
@@ -268,12 +301,13 @@ def main():
                          "checkpoint (docs/ROBUSTNESS.md); no-op for "
                          "--policy float32")
     args = ap.parse_args()
+    enable_compile_cache()
     losses, _ = train(args.arch, smoke=args.smoke, steps=args.steps,
                       batch=args.batch, seq=args.seq, policy_name=args.policy,
                       lr=args.lr, microbatch=args.microbatch,
                       ckpt_dir=args.ckpt_dir, use_wsd=args.wsd, seed=args.seed,
                       qflow=args.qflow, qweights=args.qweights,
-                      health=args.health)
+                      health=args.health, mesh_shape=args.mesh)
     print(f"final loss: {losses[-1]:.4f} (start {losses[0]:.4f})")
 
 

@@ -17,8 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = ["ShardingRules", "use_rules", "logical_constraint", "logical_to_spec",
-           "spec_tree", "DEFAULT_RULES", "MULTIPOD_RULES"]
+__all__ = ["ShardingRules", "use_rules", "current_mesh", "logical_constraint",
+           "logical_to_spec", "spec_tree", "DEFAULT_RULES", "MULTIPOD_RULES"]
 
 MeshAxes = Union[str, Tuple[str, ...], None]
 
@@ -72,6 +72,11 @@ def use_rules(rules: ShardingRules, mesh: Optional[Mesh] = None):
         yield
     finally:
         _ctx.rules, _ctx.mesh = prev
+
+
+def current_mesh() -> Optional[Mesh]:
+    """The mesh bound by :func:`use_rules` (None outside one)."""
+    return _ctx.mesh
 
 
 def logical_constraint(x: jnp.ndarray, *names: Optional[str]) -> jnp.ndarray:

@@ -334,3 +334,34 @@ def test_plan_speculative_verify_rejects_bad_geometry():
     with pytest.raises(ValueError, match="k must be >= 1"):
         dispatch.plan_speculative_verify(0, 1, 4, weight_bytes=1,
                                          cache_bytes=1)
+
+
+def test_plan_on_multi_device_mesh_keeps_jnp_for_compiled_kernels():
+    """GSPMD cannot partition a Mosaic kernel: inside a multi-device mesh
+    a compiled plan takes the (bit-identical) jnp path with a reason the
+    chip smoke run recognizes; interpret-mode kernels and one-device
+    meshes are unaffected."""
+    import types
+
+    from repro.runtime.sharding import DEFAULT_RULES, use_rules
+    four = types.SimpleNamespace(size=4)
+    with use_rules(DEFAULT_RULES, four):
+        d = _plan(kernel_mode="auto", backend="tpu")
+        assert d.path == dispatch.JNP and d.reason == dispatch.UNPARTITIONED
+        a = dispatch.plan_attention("attn_fwd", 64, 256, 64, QuantConfig(8),
+                                    s=64, backend="tpu")
+        assert a.reason == dispatch.UNPARTITIONED
+        assert _plan(kernel_mode="fused", backend="cpu").path == dispatch.FUSED
+    with use_rules(DEFAULT_RULES, types.SimpleNamespace(size=1)):
+        assert _plan(kernel_mode="auto", backend="tpu").path == dispatch.FUSED
+
+
+def test_decode_block_megakernel_is_not_planned_for_mosaic():
+    d = dispatch.plan_decode_block("qdecode_block", 1, 32, 64, 12, 2, 2, 16,
+                                   QuantConfig(8, stochastic=False),
+                                   backend="tpu")
+    assert d.path == dispatch.JNP and "Mosaic" in d.reason
+    d = dispatch.plan_decode_block("qdecode_block", 1, 32, 64, 12, 2, 2, 16,
+                                   QuantConfig(8, stochastic=False),
+                                   kernel_mode="fused", backend="cpu")
+    assert d.path == dispatch.FUSED and d.interpret

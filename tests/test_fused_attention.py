@@ -28,7 +28,7 @@ import numpy as np
 from repro.core import BFP, PAPER_INT8, NumericPolicy, dequantize, pow2, quantize
 from repro.core.bfp import QuantConfig, rounding_bits, scale_exponent
 from repro.core.qops import qcache_quantize
-from repro.kernels import dispatch, ref
+from repro.kernels import dispatch, ref, tile
 from repro.kernels import fused_attention as fa
 from repro.models.attention import (cache_decode_attention, chunked_attention,
                                     decode_attention, local_attention)
@@ -248,7 +248,7 @@ def test_fwd_integer_oracle_single_block():
     ph = ref.bfp_quantize_ref(pt, rp[0], e_row)
     np.testing.assert_array_equal(
         np.asarray(ph),
-        np.asarray(fa._quantize_tile(pt, rp[0], e_row, 7, True)))
+        np.asarray(tile.quantize_tile(pt, rp[0], e_row, 7, True)))
     # integer PV with the per-row p scale + scalar V scale epilogue
     pv = np.asarray(ph).astype(np.int64) @ np.asarray(vq.m[0]).astype(np.int64)
     scale = np.asarray(pow2(scale_exponent(e_row, QuantConfig(8))

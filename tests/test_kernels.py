@@ -156,3 +156,56 @@ def test_end_to_end_kernel_pipeline_vs_core():
     y = int8_matmul_op(mx, mw.T, ex[0], ew[0], use_pallas=True)
     ref_f = x @ w
     assert np.abs(np.asarray(y - ref_f)).max() <= 0.08 * float(jnp.abs(ref_f).max()) + 0.05
+
+
+# ---------------------------------------------------------------------------
+# shared tile helpers (kernels/tile.py): int32-only forms Mosaic legalizes
+# ---------------------------------------------------------------------------
+
+def _edge_values():
+    """Zeros of both signs, sub-normals, the largest normals, values far
+    below the shared exponent (shift >= 32) and plain randoms."""
+    rng = np.random.RandomState(5)
+    special = np.array([0.0, -0.0, 1e-45, -3e-39, 1.1754942e-38, 3.4e38,
+                        -3.4e38, 1e-30, -2.0 ** -100, 1.0, -1.0, 0.5,
+                        0.49999997, 127.0, -128.0, 65504.0], np.float32)
+    x = rng.randn(8, 128).astype(np.float32) * 10.0 ** rng.randint(
+        -20, 20, (8, 128))
+    x.flat[:special.size] = special
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("stochastic", [True, False])
+def test_tile_quantizer_matches_reference_on_edge_values(stochastic):
+    from repro.kernels import tile
+    x = _edge_values()
+    e = ref.max_biased_exp_ref(x)
+    rand = jax.random.bits(KEY, x.shape, jnp.uint32)
+    got = tile.quantize_tile(x, rand if stochastic else None, e, 7,
+                             stochastic)
+    want = quantize(x, QuantConfig(8, stochastic=stochastic), KEY).m
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if stochastic:
+        # also against the unsigned reference, with a shared exponent far
+        # BELOW the large elements (negative shift: they map to 0)
+        for e_sh in (e, e - 40):
+            np.testing.assert_array_equal(
+                np.asarray(tile.quantize_tile(x, rand, e_sh, 7, True)),
+                np.asarray(ref.bfp_quantize_ref(x, rand, e_sh)))
+
+
+def test_tile_unsigned_compare_and_pow2():
+    from repro.kernels import tile
+    a = jnp.asarray(np.array([0, 1, -1, 2 ** 31 - 1, -2 ** 31, 5],
+                             np.int64).astype(np.int32))
+    b = jnp.asarray(np.array([1, 0, 0, -2 ** 31, 2 ** 31 - 1, 5],
+                             np.int64).astype(np.int32))
+    want = (np.asarray(a).view(np.uint32) < np.asarray(b).view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(tile.ult(a, b)), want)
+    e = jnp.arange(-130, 130, dtype=jnp.int32)
+    np.testing.assert_array_equal(np.asarray(tile.pow2_f32(e)),
+                                  np.asarray(pow2(e)))
+    # scalars come back as (1, 1) tiles (Mosaic bitcasts vectors only)
+    assert tile.pow2_f32(jnp.int32(3)).shape == (1, 1)
+    assert tile.eff_exp(jnp.float32(1.0)).shape == (1, 1)
+    assert int(tile.eff_exp(jnp.float32(1.0))[0, 0]) == 127

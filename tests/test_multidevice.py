@@ -1,5 +1,7 @@
 """Multi-device tests (8 fake CPU devices via subprocess: XLA_FLAGS must be
-set before jax initializes, so these run as child processes)."""
+set before jax initializes, so these run as child processes).  The
+children are pinned to the CPU platform: on a machine with an accelerator
+they must never reach for a device the parent may hold."""
 
 import os
 import subprocess
@@ -10,6 +12,7 @@ import pytest
 
 _ENV = {**os.environ,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..", "src")}
 
 
@@ -23,21 +26,21 @@ def _run(code: str) -> str:
 def test_quantized_psum_matches_float_psum():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, Mesh, PartitionSpec as P
+        from jax import shard_map
         from functools import partial
         from repro.runtime.compression import quantized_psum, psum16
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         x = jnp.asarray(np.random.RandomState(0).randn(8, 16, 32).astype(np.float32))
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data"), P()),
-                 out_specs=P("data"), check_rep=False)
+                 out_specs=P("data"), check_vma=False)
         def f8(x, key):
             return quantized_psum(x[0], "data", key)[None]
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data"), P()),
-                 out_specs=P("data"), check_rep=False)
+                 out_specs=P("data"), check_vma=False)
         def f16(x, key):
             return psum16(x[0], "data", key)[None]
 
@@ -57,16 +60,16 @@ def test_quantized_psum_matches_float_psum():
 def test_quantized_psum_unbiased():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, Mesh, PartitionSpec as P
+        from jax import shard_map
         from functools import partial
         from repro.runtime.compression import quantized_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         x = jnp.asarray(np.random.RandomState(1).randn(8, 8, 8).astype(np.float32))
 
         @partial(shard_map, mesh=mesh, in_specs=(P("data"), P()),
-                 out_specs=P("data"), check_rep=False)
+                 out_specs=P("data"), check_vma=False)
         def f(x, key):
             return quantized_psum(x[0], "data", key)[None]
 
@@ -98,7 +101,8 @@ def test_model_loss_under_pjit_dp_tp():
         from repro.core import PAPER_INT8
         from repro.runtime.sharding import DEFAULT_RULES, spec_tree, use_rules
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_local_mesh
+        mesh = make_local_mesh(2, 4)
         cfg = get_smoke_config("qwen2_0_5b")
         mod = get_model(cfg)
         key = jax.random.key(0)

@@ -189,3 +189,17 @@ def test_schedules_shapes_and_endpoints():
     assert float(w) == pytest.approx(0.5)
     mid = wsd_schedule(jnp.int32(60), 1.0, 10, 100, 50)
     assert float(mid) == pytest.approx(1.0)
+
+
+def test_mesh_shape_parsing_and_auto_axes():
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_local_mesh, parse_mesh_shape
+    assert parse_mesh_shape("2,2") == (2, 2)
+    assert parse_mesh_shape("1,1") == (1, 1)
+    for bad in ("2", "0,2", "2,2,1", "a,b"):
+        with pytest.raises(ValueError):
+            parse_mesh_shape(bad)
+    mesh = make_local_mesh()
+    assert mesh.axis_names == ("data", "model")
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
