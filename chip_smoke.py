@@ -204,7 +204,7 @@ def main() -> int:
     # routing comes from committed code alone: never read an autotune
     # cache from outside the checkout, never measure.
     os.environ.pop("REPRO_KERNEL_AUTOTUNE", None)
-    at_cache = os.path.join(ROOT, "chiprun_out", "chip_smoke_autotune.json")
+    at_cache = os.path.join(ROOT, ".autotune", "chip_smoke.json")
     if os.path.exists(at_cache):
         os.remove(at_cache)
     os.environ["REPRO_KERNEL_AUTOTUNE_CACHE"] = at_cache

@@ -36,14 +36,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_local_mesh(data: int = 1, model: int = 1,
                     devices: Optional[Sequence] = None):
     """(data, model) mesh over the first ``data * model`` local devices
-    (or over ``devices``): tests, CPU examples, the train driver."""
+    (or over ``devices``): tests, CPU examples, ``launch/train.py``."""
     if devices is None:
         devices = jax.devices()[:data * model]
     return _mesh((data, model), ("data", "model"), devices)
 
 
 def parse_mesh_shape(text: str):
-    """``"2,2"`` -> (2, 2): the train driver's ``--mesh DATA,MODEL``."""
+    """``"2,2"`` -> (2, 2): ``launch/train.py --mesh DATA,MODEL``."""
     parts = [int(p) for p in text.split(",")]
     if len(parts) != 2 or min(parts) < 1:
         raise ValueError(f"--mesh takes DATA,MODEL (e.g. 2,2), got {text!r}")
