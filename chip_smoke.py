@@ -50,9 +50,9 @@ BATCH, SEQ, TRAIN_STEPS = 4, 512, 3
 N_REQUESTS, PROMPT_LEN, GEN = 4, 32, 16
 LOSS_BAND = 0.01          # int8 vs float32 first-batch loss, relative
 MESH_TOL = 1e-4           # 2x2 mesh vs one chip step-1 loss, relative
-# jnp plans that are a property of the shape, not of the backend
-_SHAPE_REASONS = ("accum_chunk", "overflows the int32 accumulator",
-                  "vmem_budget")
+# jnp plans that are a property of the shape, not of the backend or of
+# the VMEM residency model (a block that does not fit plans UNFUSED)
+_SHAPE_REASONS = ("accum_chunk", "overflows the int32 accumulator")
 
 
 class SmokeFailure(SystemExit):

@@ -407,38 +407,41 @@ def _decode_block_core(x, wqkv_m, se_qkv, wo_m, se_o, wgu_m, se_gu, wd_m,
         eps_m=eps_m, eps_e=eps_e, center=False, stochastic=False)
     qkv = int8_dot(xq1, wqkv_m).astype(jnp.float32) * pow2_f32(se1 + se_qkv)
     nq, nk = hq * dh, hkv * dh
-    q = qkv[:, :nq].reshape(b, hq, dh)
-    k = qkv[:, nq:nq + nk].reshape(b, hkv, dh)
-    v = qkv[:, nq + nk:].reshape(b, hkv, dh)
-    q = _rope_half(q, cos, sin)
-    k = _rope_half(k, cos, sin)
+
+    def head(col, bi):                  # one head of one row: (1, dh)
+        return qkv[bi:bi + 1, col:col + dh]
 
     # --- fresh K/V rows: the qcache_append currency (nearest, per-row) ---
-    k2 = k.reshape(b * hkv, dh)
-    v2 = v.reshape(b * hkv, dh)
+    k2 = jnp.concatenate([_rope_half(head(nq + h * dh, bi), cos, sin)
+                          for bi in range(b) for h in range(hkv)], axis=0)
+    v2 = jnp.concatenate([head(nq + nk + h * dh, bi)
+                          for bi in range(b) for h in range(hkv)], axis=0)
     k_new, ek_new = _row_quantize(k2, None, p)
     v_new, ev_new = _row_quantize(v2, None, p)
 
     # --- decode attention per (batch, kv-head) group over the cache ---
     qpos = jnp.full((gs, 1), pos, jnp.int32)
-    attn = []
+    at_pos = lax.broadcasted_iota(jnp.int32, (km.shape[2], 1), 0) == pos
+    rows = []
     for bi in range(b):
+        attn = []
         for h in range(hkv):
-            km_f = lax.dynamic_update_slice(
-                km[bi, h], k_new[bi * hkv + h][None, :], (pos, 0))
-            ke_f = lax.dynamic_update_slice(
-                ke[bi, h], ek_new[bi * hkv + h][None, :], (pos, 0))
-            vm_f = lax.dynamic_update_slice(
-                vm[bi, h], v_new[bi * hkv + h][None, :], (pos, 0))
-            ve_f = lax.dynamic_update_slice(
-                ve[bi, h], ev_new[bi * hkv + h][None, :], (pos, 0))
-            qg = q[bi, h * gs:(h + 1) * gs]
+            r = bi * hkv + h
+            km_f = jnp.where(at_pos, k_new[r:r + 1], km[bi, h])
+            ke_f = jnp.where(at_pos, ek_new[r:r + 1], ke[bi, h])
+            vm_f = jnp.where(at_pos, v_new[r:r + 1], vm[bi, h])
+            ve_f = jnp.where(at_pos, ev_new[r:r + 1], ve[bi, h])
+            qg = _rope_half(jnp.concatenate(
+                [head((h * gs + g) * dh, bi) for g in range(gs)], axis=0),
+                cos, sin)
             eq = eff_exp(qg).max()
             qm = quantize_tile(qg, None, eq, p, False)
-            attn.append(_decode_core(
+            y_g = _decode_core(
                 qm, km_f, vm_f, ke_f[:, 0], ve_f[:, 0], None, eq, qpos,
-                pos + 1, p=p, causal=True, window=window))
-    y = jnp.stack(attn).reshape(b, hq * dh)
+                pos + 1, p=p, causal=True, window=window)
+            attn += [y_g[g:g + 1] for g in range(gs)]
+        rows.append(jnp.concatenate(attn, axis=1))
+    y = jnp.concatenate(rows, axis=0)
 
     # --- out projection + residual ---
     aq, ea = _row_quantize(y, None, p)
@@ -466,8 +469,8 @@ def _decode_block_kernel(pos_ref, x_ref, wqkv_ref, sqkv_ref, wo_ref, so_ref,
                          se_g1, se_g2):
     """grid=(1,): the whole layer in one program, all operands resident."""
     dh_ = cs_ref.shape[-1] // 2
-    cos = cs_ref[...][:, :dh_]
-    sin = cs_ref[...][:, dh_:]
+    cos = cs_ref[:, :dh_]
+    sin = cs_ref[:, dh_:]
     out, kn, ekn, vn, evn = _decode_block_core(
         x_ref[...], wqkv_ref[...], sqkv_ref[...], wo_ref[...], so_ref[...],
         wgu_ref[...], sgu_ref[...], wd_ref[...], sd_ref[...],

@@ -12,8 +12,6 @@ which a gather from a sharded table must name its output sharding.)
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
-
 import jax
 from jax.sharding import AxisType
 
@@ -33,13 +31,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mesh(shape, axes)
 
 
-def make_local_mesh(data: int = 1, model: int = 1,
-                    devices: Optional[Sequence] = None):
-    """(data, model) mesh over the first ``data * model`` local devices
-    (or over ``devices``): tests, CPU examples, ``launch/train.py``."""
-    if devices is None:
-        devices = jax.devices()[:data * model]
-    return _mesh((data, model), ("data", "model"), devices)
+def make_local_mesh(data: int = 1, model: int = 1):
+    """(data, model) mesh over the first ``data * model`` local devices:
+    tests, CPU examples, ``launch/train.py``."""
+    return _mesh((data, model), ("data", "model"),
+                 jax.devices()[:data * model])
 
 
 def parse_mesh_shape(text: str):

@@ -356,12 +356,24 @@ def test_plan_on_multi_device_mesh_keeps_jnp_for_compiled_kernels():
         assert _plan(kernel_mode="auto", backend="tpu").path == dispatch.FUSED
 
 
-def test_decode_block_megakernel_is_not_planned_for_mosaic():
-    d = dispatch.plan_decode_block("qdecode_block", 1, 32, 64, 12, 2, 2, 16,
-                                   QuantConfig(8, stochastic=False),
-                                   backend="tpu")
-    assert d.path == dispatch.JNP and "Mosaic" in d.reason
-    d = dispatch.plan_decode_block("qdecode_block", 1, 32, 64, 12, 2, 2, 16,
-                                   QuantConfig(8, stochastic=False),
-                                   kernel_mode="fused", backend="cpu")
+def test_decode_block_megakernel_plans_for_mosaic_within_budget():
+    """The whole-layer decode kernel lowers through Mosaic: a layer that
+    fits the residency budget plans the compiled kernel on a TPU, a
+    published-width layer (qwen2-0.5b: 15.5 MiB resident) keeps the
+    per-op path, and a multi-device mesh keeps the jnp path."""
+    import types
+
+    from repro.runtime.sharding import DEFAULT_RULES, use_rules
+    qc = QuantConfig(8, stochastic=False)
+    small = ("qdecode_block", 1, 32, 64, 12, 2, 2, 16, qc)
+    d = dispatch.plan_decode_block(*small, backend="tpu")
+    assert d.path == dispatch.FUSED and not d.interpret
+    d = dispatch.plan_decode_block("qdecode_block", 4, 896, 4864, 256, 14,
+                                   2, 64, qc, backend="tpu")
+    assert d.path == dispatch.JNP and "vmem_budget" in d.reason
+    with use_rules(DEFAULT_RULES, types.SimpleNamespace(size=4)):
+        d = dispatch.plan_decode_block(*small, backend="tpu")
+        assert d.reason == dispatch.UNPARTITIONED
+    d = dispatch.plan_decode_block(*small, kernel_mode="fused",
+                                   backend="cpu")
     assert d.path == dispatch.FUSED and d.interpret

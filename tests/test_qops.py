@@ -70,7 +70,8 @@ def test_accum_chunking_matches_unchunked():
 def test_chunk_count_prime_k_regression():
     """The old divisor search (`while k % n: n += 1`) degenerated for prime
     K: ceil(509/128)=4 walked all the way to n=509, i.e. 509 chunks of ONE
-    element.  _pt_dot now zero-pads K instead, so the count stays ceil."""
+    element.  _pt_dot now lets the last chunk run short instead, so the
+    count stays ceil."""
     from repro.core.qops import _chunk_count
     assert _chunk_count(509, 128) == 4          # was 509 before the fix
     assert _chunk_count(509, 509) == 1
@@ -81,6 +82,44 @@ def test_chunk_count_prime_k_regression():
         n = _chunk_count(k, 128)
         assert n == -(-k // 128)
         assert n * (-(-k // n)) >= k            # padded chunks cover K
+
+
+def _pt_dot_padded_reduce(am, bm, nbatch, nchunk):
+    """The chunked dot as one chunk-batched contraction: K zero-padded to
+    nchunk equal chunks, int32 partials summed in f32 by one reduce."""
+    k = am.shape[-1]
+    kc = -(-k // nchunk)
+    widths = [(0, 0)] * (am.ndim - 1) + [(0, nchunk * kc - k)]
+    a4 = jnp.moveaxis(jnp.pad(am, widths).reshape(*am.shape[:-1], nchunk, kc),
+                      -2, nbatch)
+    b4 = jnp.moveaxis(jnp.pad(bm, widths).reshape(*bm.shape[:-1], nchunk, kc),
+                      -2, nbatch)
+    acc = jax.lax.dot_general(
+        a4, b4, (((a4.ndim - 1,), (b4.ndim - 1,)),
+                 (tuple(range(nbatch + 1)), tuple(range(nbatch + 1)))),
+        preferred_element_type=jnp.int32)
+    return acc.astype(jnp.float32).sum(axis=nbatch)
+
+
+@pytest.mark.parametrize("batch,k,nchunk", [((), 8191, 3), ((), 9000, 4),
+                                            ((2,), 7001, 5)])
+def test_pt_dot_chunks_bitwise_equal_padded_reduce(batch, k, nchunk):
+    """_pt_dot's per-slice dots combined in chunk order equal, bit for bit,
+    the padded chunk-batched dot reduced over its chunk axis.  Mantissas
+    are large and positive, so each int32 partial exceeds 2^24 and the f32
+    combine rounds: a different combine order would show."""
+    from repro.core.qops import _pt_dot
+    rng = np.random.RandomState(k)
+    mk = lambda *s: rng.randint(100, 128, s).astype(np.int8)
+    am, bm = mk(*batch, 5, k), mk(*batch, 7, k)
+    nb = len(batch)
+    got = jax.jit(_pt_dot, static_argnums=(2, 3))(am, bm, nb, nchunk)
+    want = jax.jit(_pt_dot_padded_reduce, static_argnums=(2, 3))(am, bm, nb,
+                                                                  nchunk)
+    exact = np.einsum("...mk,...nk->...mn", am.astype(np.int64),
+                      bm.astype(np.int64))
+    assert np.any(np.asarray(want, np.float64) != exact)     # f32 rounded
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_accum_chunking_prime_k_matches_unchunked():

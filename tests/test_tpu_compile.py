@@ -4,7 +4,8 @@ Every Pallas kernel the TPU routing plan picks at qwen2-0.5b's published
 widths (d_model 896, 14/2 heads of 64, d_ff 4864, vocab 151936; a train
 step of M = 4096 token rows, batch 8 x seq 512) is lowered and compiled
 by the TPU compiler (Mosaic) for one chip of a described ``v5e:2x2``
-topology, plus the full-width decode step.  Interpret-mode tests cannot
+topology, plus the full-width decode step and the whole-layer decode
+kernel at layer sizes its residency holds.  Interpret-mode tests cannot
 see what this catches: ops Mosaic does not legalize, and blocks that
 overrun the scoped VMEM limit the residency model is meant to respect.
 Each test also checks that the plan picks the kernel path, and that the
@@ -182,6 +183,36 @@ def test_chain_kernels_compile(one_chip):
         act="silu_glu", out_q=True, m_true=M, interpret=False),
         S((M, k), F32), S((M, k), U32), S((n, k), I8), S((M, n // 2), U32),
         S((), I32))
+
+
+# (b, d, d_ff, hq, hkv, dh, t): whole decoder layers that fit the decode
+# kernel's residency: a smoke-width block, and qwen2-0.5b's attention
+# (7 query heads per kv head) with a narrower MLP.
+DECODE_BLOCKS = [(2, 256, 256, 4, 2, 64, 64), (4, D_MODEL, 2048, 14, 2, DH, 256)]
+
+
+@pytest.mark.parametrize("b,d,n_ff,hq,hkv,dh,t", DECODE_BLOCKS,
+                         ids=[f"d{c[1]}-ff{c[2]}-h{c[3]}" for c in
+                              DECODE_BLOCKS])
+def test_decode_block_kernel_compiles(one_chip, b, d, n_ff, hq, hkv, dh, t):
+    """The whole-layer decode kernel (norm, QKV, decode attention over the
+    quantized cache with the fresh row spliced in, out-proj, gated MLP)."""
+    dec = D.plan_decode_block("qdecode_block", b, d, n_ff, t, hq, hkv, dh,
+                              dataclasses.replace(CFG, stochastic=False),
+                              backend="tpu")
+    assert dec.path == D.FUSED and not dec.interpret, dec
+    S = _shape(one_chip)
+    nqkv = (hq + 2 * hkv) * dh
+    _compile(lambda *a: D.run_decode_block(
+        *a, dec, n_d=d, n_ff=n_ff, hq=hq, hkv=hkv, dh=dh, window=32,
+        se_g1=-15, se_g2=-15),
+        S((b, d), F32), S((nqkv, d), I8), S((1, nqkv), I32),
+        S((d, hq * dh), I8), S((1, d), I32), S((2 * n_ff, d), I8),
+        S((1, 2 * n_ff), I32), S((d, n_ff), I8), S((1, d), I32),
+        S((1, d), I32), S((1, d), I32),
+        S((b, hkv, t, dh), I8), S((b, hkv, t, 1), I32),
+        S((b, hkv, t, dh), I8), S((b, hkv, t, 1), I32),
+        S((1, 2 * dh), F32), S((), I32))
 
 
 def test_decode_step_compiles(one_chip, monkeypatch):
