@@ -55,6 +55,7 @@ import numpy as np
 
 from ..runtime import fault_injection
 from ..runtime.qpool import PoolExhausted, QPool
+from ..runtime.spans import Recorder
 from .speculative import draft_config, draft_params, make_spec_decode_step
 from .steps import make_decode_step, make_prefill_step, quantize_serving_params
 
@@ -127,6 +128,14 @@ class _Running:
     @property
     def done(self) -> bool:
         return len(self.tokens) >= self.req.gen
+
+
+def _host_nbytes(*trees) -> int:
+    """Bytes of the host (numpy) arrays in ``trees`` as a jitted call
+    copies them to the device (in JAX's canonical dtypes)."""
+    return sum(x.size * jax.dtypes.canonicalize_dtype(x.dtype).itemsize
+               for t in trees for x in jax.tree_util.tree_leaves(t)
+               if isinstance(x, (np.ndarray, np.generic)))
 
 
 def _priority(run: _Running):
@@ -222,6 +231,9 @@ class Engine:
         self.shed: Dict[int, str] = {}
         self.n_retries = 0
         self.eff_max_batch = ecfg.max_batch
+        # host spans and counters (runtime/spans.py, docs/SERVING.md
+        # §Spans and counters); recorded in memory only while started.
+        self.spans = Recorder()
         if guard is not None:
             guard.attach(self)
 
@@ -234,6 +246,7 @@ class Engine:
                     f"request {r.rid}: prompt {len(r.prompt)} + gen {r.gen} "
                     f"exceeds engine max_len {self.ecfg.max_len}")
             self._pending.append(r)
+            self.spans.begin(("queued", r.rid), "engine.queued", rid=r.rid)
         self._pending.sort(key=lambda r: (r.arrival_step, r.rid))
 
     # -- request-local randomness (serve.py-identical) ----------------------
@@ -263,10 +276,11 @@ class Engine:
             need = self.pool.pages_needed(ckpt["length"])
             if self.pool.free_pages - need < self.ecfg.watermark:
                 return
-            self._preempted.pop(0)
-            self.pool.readmit(run.req.rid, ckpt)
-            run.last_progress_step = self.clock
-            self._running[run.req.rid] = run
+            with self.spans.span("engine.admit", rid=run.req.rid):
+                self._preempted.pop(0)
+                self.pool.readmit(run.req.rid, ckpt)
+                run.last_progress_step = self.clock
+                self._running[run.req.rid] = run
             return
         if not self._waiting:
             return
@@ -276,32 +290,40 @@ class Engine:
         need = self.pool.pages_needed(len(req.prompt))
         if self.pool.free_pages - need < self.ecfg.watermark:
             return
-        self._waiting.pop(0)
-        self.pool.admit(req.rid)
-        self.pool.ensure_capacity(req.rid, len(req.prompt))
-        run = _Running(req, last_progress_step=self.clock)
-        self._running[req.rid] = run
-        self._do_prefill(run)
+        self.spans.end(("queued", req.rid))
+        with self.spans.span("engine.admit", rid=req.rid):
+            self._waiting.pop(0)
+            self.pool.admit(req.rid)
+            self.pool.ensure_capacity(req.rid, len(req.prompt))
+            run = _Running(req, last_progress_step=self.clock)
+            self._running[req.rid] = run
+            self._do_prefill(run)
 
     def _prefill_call(self, req: Request):
         """The jitted prefill at this request's batch-1 shape — shared by
         admission and guard lane recovery (both must hit the same program
-        with the same key for the bitwise invariant)."""
-        batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None]}
+        with the same key for the bitwise invariant).  The batch goes in
+        as host arrays, so a new prompt length compiles the prefill
+        program and nothing else."""
+        batch = {"tokens": np.asarray(req.prompt, np.int32)[None]}
         for name, arr in (req.extras or {}).items():
-            batch[name] = jnp.asarray(arr)[None]
+            batch[name] = np.asarray(arr)[None]
+        self.spans.count("prefill.h2d_bytes", _host_nbytes(batch))
         return self._prefill(self.params, batch, self._prefill_key(req))
 
     def _do_prefill(self, run: _Running) -> None:
-        req = run.req
-        cache, logits = self._prefill_call(req)
-        tok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+        req, sp = run.req, self.spans
+        with sp.span("engine.prefill", rid=req.rid):
+            cache, logits = self._prefill_call(req)
+            tok = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
         run.tokens.append(tok)
         run.last_progress_step = self.clock
         self.ttft_steps[req.rid] = self.clock - req.arrival_step
-        host = jax.tree_util.tree_map(np.asarray, cache)
-        self.pool.write(req.rid, host, upto=len(req.prompt))
-        self._retire_if_done(run)
+        with sp.span("engine.prefill_write", rid=req.rid):
+            host = jax.tree_util.tree_map(np.asarray, cache)
+            sp.count("prefill.d2h_bytes", _host_nbytes(tok, host))
+            self.pool.write(req.rid, host, upto=len(req.prompt))
+            self._retire_if_done(run)
 
     def _is_spec(self, run: _Running) -> bool:
         return (self.ecfg.speculate > 0 and run.req.speculate
@@ -430,35 +452,77 @@ class Engine:
         if spec:
             self._decode_spec(spec)
 
-    def _decode_plain(self, lanes: List[_Running]) -> None:
+    def _gather_lanes(self, lanes: List[_Running]):
+        """Each lane's contiguous cache out of the pool and its last
+        token; under vmap padded to ``max_batch`` with zero-cache lanes
+        and stacked (host numpy).  Returns (caches, tokens, pad)."""
         caches = [self.pool.gather(r.req.rid) for r in lanes]
         toks = [np.asarray(r.tokens[-1], np.int32) for r in lanes]
         if self.ecfg.max_batch == 1:
+            return caches[0], toks[0], 0
+        pad = self.ecfg.max_batch - len(lanes)
+        caches += [self.pool.empty_cache()] * pad
+        vcache = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *caches)
+        vtok = np.stack(toks + [np.zeros(1, np.int32)] * pad)
+        return vcache, vtok, pad
+
+    def _lane_keys(self, keys, pad: int):
+        """The lanes' raw decode keys on the host, padded with zeros."""
+        return np.stack(
+            [np.asarray(jax.random.key_data(k)) for k in keys]
+            + [np.zeros_like(np.asarray(jax.random.key_data(
+                jax.random.key(0))))] * pad)
+
+    def _fetch_lanes(self, vcaches, n: int):
+        """The first ``n`` lanes' caches back from the device."""
+        out = [jax.tree_util.tree_map(lambda a, j=j: np.asarray(a[j]),
+                                      vcaches) for j in range(n)]
+        self.spans.count("engine.d2h_bytes", _host_nbytes(out))
+        return out
+
+    def _decode_plain(self, lanes: List[_Running]) -> None:
+        sp = self.spans
+        with sp.span("engine.gather", lanes=len(lanes)):
+            vcache, vtok, pad = self._gather_lanes(lanes)
+            vpos = np.asarray([r.pos for r in lanes] + [0] * pad, np.int32)
+        sp.count("engine.lanes", len(lanes))
+        sp.count("engine.pad_lanes", pad)
+        if self.ecfg.max_batch == 1:
             # the exact batch-1 program serve.py runs (golden pin).
             run = lanes[0]
-            logits, cache = self._decode1(
-                self.params, caches[0], jnp.asarray(toks[0]),
-                jnp.int32(run.pos), self._decode_key(run.req, run.n_decoded))
-            out_toks = [np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))]
-            out_caches = [jax.tree_util.tree_map(np.asarray, cache)]
-        else:
-            pad = self.ecfg.max_batch - len(lanes)
-            caches += [self.pool.empty_cache()] * pad
-            vcache = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *caches)
-            vtok = np.stack(toks + [np.zeros(1, np.int32)] * pad)
-            vpos = np.asarray([r.pos for r in lanes] + [0] * pad, np.int32)
-            vkey = np.stack(
-                [np.asarray(jax.random.key_data(
-                    self._decode_key(r.req, r.n_decoded))) for r in lanes]
-                + [np.zeros_like(np.asarray(jax.random.key_data(
-                    jax.random.key(0))))] * pad)
+            with sp.span("engine.keys"):
+                key = self._decode_key(run.req, run.n_decoded)
+            with sp.span("engine.decode"):
+                sp.count("engine.h2d_bytes", _host_nbytes(vcache, vtok, vpos))
+                logits, cache = self._decode1(self.params, vcache, vtok,
+                                              vpos[0], key)
+                out_toks = [np.asarray(
+                    jnp.argmax(logits, -1).astype(jnp.int32))]
+                sp.count("engine.d2h_bytes", _host_nbytes(out_toks))
+            with sp.span("engine.scatter"):
+                out_caches = [jax.tree_util.tree_map(np.asarray, cache)]
+                sp.count("engine.d2h_bytes", _host_nbytes(out_caches))
+                self._commit_plain(lanes, out_toks, out_caches)
+            return
+        with sp.span("engine.keys"):
+            vkey = self._lane_keys(
+                [self._decode_key(r.req, r.n_decoded) for r in lanes], pad)
+        with sp.span("engine.decode"):
+            sp.count("engine.h2d_bytes",
+                     _host_nbytes(vcache, vtok, vpos, vkey))
             vlogits, vcaches = self._decodeN(self.params, vcache, vtok,
                                              vpos, vkey)
             vout = np.asarray(jnp.argmax(vlogits, -1).astype(jnp.int32))
-            out_toks = [vout[j] for j in range(len(lanes))]
-            out_caches = [jax.tree_util.tree_map(
-                lambda a, j=j: np.asarray(a[j]), vcaches)
-                for j in range(len(lanes))]
+            sp.count("engine.d2h_bytes", vout.nbytes)
+        with sp.span("engine.scatter"):
+            out_caches = self._fetch_lanes(vcaches, len(lanes))
+            self._commit_plain(lanes, [vout[j] for j in range(len(lanes))],
+                               out_caches)
+            # the step's buffers are released inside the span: freeing
+            # some hundreds of MB of host arrays can take milliseconds
+            del vcache, vcaches, out_caches
+
+    def _commit_plain(self, lanes, out_toks, out_caches) -> None:
         for run, tok, host in zip(lanes, out_toks, out_caches):
             block = run.pos // self.pool.page_size
             self.pool.write(run.req.rid, host,
@@ -475,45 +539,57 @@ class Engine:
         table exactly like sequential steps would have (the verify scan
         IS the sequential program), then ``trim_capacity`` hands the
         over-reserved tail pages straight back to the free list."""
-        k = self.ecfg.speculate
-        caches = [self.pool.gather(r.req.rid) for r in lanes]
-        toks = [np.asarray(r.tokens[-1], np.int32) for r in lanes]
-        # commit budget: tokens still owed, clamped to the reservation the
-        # scheduler actually got (a degraded lane just commits fewer).
-        mcs = [min(self._spec_budget(r),
-                   self.pool.capacity(r.req.rid) - r.pos) for r in lanes]
-        if self.ecfg.max_batch == 1:
-            run = lanes[0]
-            targets, commit, cache = self._spec1(
-                self.params, self._draft_params, caches[0],
-                jnp.asarray(toks[0]), jnp.int32(run.pos),
-                jnp.int32(run.n_decoded), jax.random.key(run.req.seed),
-                jnp.int32(mcs[0]))
-            outs = [(np.asarray(targets), int(np.asarray(commit)[0]),
-                     jax.tree_util.tree_map(np.asarray, cache))]
-        else:
-            pad = self.ecfg.max_batch - len(lanes)
-            caches += [self.pool.empty_cache()] * pad
-            vcache = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *caches)
-            vtok = np.stack(toks + [np.zeros(1, np.int32)] * pad)
+        sp = self.spans
+        with sp.span("engine.gather", lanes=len(lanes)):
+            vcache, vtok, pad = self._gather_lanes(lanes)
             vpos = np.asarray([r.pos for r in lanes] + [0] * pad, np.int32)
             vi0 = np.asarray([r.n_decoded for r in lanes] + [0] * pad,
                              np.int32)
-            vkey = np.stack(
-                [np.asarray(jax.random.key_data(
-                    jax.random.key(r.req.seed))) for r in lanes]
-                + [np.zeros_like(np.asarray(jax.random.key_data(
-                    jax.random.key(0))))] * pad)
+            # commit budget: tokens still owed, clamped to the reservation
+            # the scheduler actually got (a degraded lane commits fewer).
+            mcs = [min(self._spec_budget(r),
+                       self.pool.capacity(r.req.rid) - r.pos) for r in lanes]
             vmc = np.asarray(mcs + [1] * pad, np.int32)
+        sp.count("engine.lanes", len(lanes))
+        sp.count("engine.pad_lanes", pad)
+        if self.ecfg.max_batch == 1:
+            run = lanes[0]
+            with sp.span("engine.keys"):
+                key = jax.random.key(run.req.seed)
+            with sp.span("engine.decode"):
+                sp.count("engine.h2d_bytes",
+                         _host_nbytes(vcache, vtok, vpos, vi0, vmc))
+                targets, commit, cache = self._spec1(
+                    self.params, self._draft_params, vcache, vtok, vpos[0],
+                    vi0[0], key, vmc[0])
+                targets, commit = np.asarray(targets), np.asarray(commit)
+                sp.count("engine.d2h_bytes", _host_nbytes(targets, commit))
+            with sp.span("engine.scatter"):
+                host = jax.tree_util.tree_map(np.asarray, cache)
+                sp.count("engine.d2h_bytes", _host_nbytes(host))
+                self._commit_spec(lanes, mcs, [(targets, int(commit[0]),
+                                                host)])
+            return
+        with sp.span("engine.keys"):
+            vkey = self._lane_keys(
+                [jax.random.key(r.req.seed) for r in lanes], pad)
+        with sp.span("engine.decode"):
+            sp.count("engine.h2d_bytes",
+                     _host_nbytes(vcache, vtok, vpos, vi0, vkey, vmc))
             vtargets, vcommit, vcaches = self._specN(
                 self.params, self._draft_params, vcache, vtok, vpos, vi0,
                 vkey, vmc)
             vtargets = np.asarray(vtargets)
             vcommit = np.asarray(vcommit)
-            outs = [(vtargets[j], int(vcommit[j][0]),
-                     jax.tree_util.tree_map(lambda a, j=j: np.asarray(a[j]),
-                                            vcaches))
-                    for j in range(len(lanes))]
+            sp.count("engine.d2h_bytes", _host_nbytes(vtargets, vcommit))
+        with sp.span("engine.scatter"):
+            hosts = self._fetch_lanes(vcaches, len(lanes))
+            self._commit_spec(lanes, mcs, [
+                (vtargets[j], int(vcommit[j][0]), hosts[j])
+                for j in range(len(lanes))])
+            del vcache, vcaches, hosts
+
+    def _commit_spec(self, lanes, mcs, outs) -> None:
         page = self.pool.page_size
         for run, mc, (targets, m, host) in zip(lanes, mcs, outs):
             rid = run.req.rid
@@ -537,6 +613,10 @@ class Engine:
 
     def step(self) -> int:
         """One simulated scheduler step; returns tokens emitted."""
+        with self.spans.span("engine.step"):
+            return self._step()
+
+    def _step(self) -> int:
         self.clock += 1
         while self._pending and self._pending[0].arrival_step <= self.clock:
             self._waiting.append(self._pending.pop(0))
@@ -546,7 +626,8 @@ class Engine:
             len(r.tokens) for r in self._running.values()) + sum(
             len(rc[0].tokens) for rc in self._preempted)
         self._admit_one()
-        lanes = self._reserve_or_preempt()
+        with self.spans.span("engine.reserve"):
+            lanes = self._reserve_or_preempt()
         # an injected lane stall models a hung device: the lane keeps its
         # pages but gets no decode work, so only the guard's stall
         # watchdog (or a shed) can get it moving again.  With nothing
