@@ -21,8 +21,9 @@ Determinism contract (everything is pinned by tests):
   program.  Each lane traces at batch-1 shapes, so per-tensor quantizer
   reductions, stochastic-rounding bits and cache appends are per-lane
   bit-identical to running the stream alone (``test_engine.py`` pins
-  vmap-lane == plain).  Part-empty batches are padded with a zero-cache
-  lane and the padding discarded — one compiled program for the whole run.
+  vmap-lane == plain).  Part-empty batches are padded with lanes read
+  from the pool's zero page, whose writes go to its scratch page — one
+  compiled program for the whole run.
 - the clock is SIMULATED scheduler steps, not wall time: TTFT and
   tokens/s-per-step are deterministic and CI-stable
   (``benchmarks/serving_bench.py``).
@@ -39,8 +40,10 @@ Scheduler, one ``step()``:
    arrival, highest rid) is evicted and re-queued until the allocation
    fits.
 4. decode: one batched step over all running lanes — gather each lane's
-   contiguous cache through its page table, run, scatter back the one
-   dirty block plus the state page.  Finished sequences hand their pages
+   contiguous cache through its page table on the device, run, write the
+   one dirty block plus the state page back into the pool on the device.
+   Only tokens, positions, keys and page-table arrays go in from the
+   host, and only tokens come back.  Finished sequences hand their pages
    straight back to the free list.
 """
 
@@ -54,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..runtime import fault_injection
-from ..runtime.qpool import PoolExhausted, QPool
+from ..runtime.qpool import PoolExhausted, QPool, host_nbytes
 from ..runtime.spans import Recorder
 from .speculative import draft_config, draft_params, make_spec_decode_step
 from .steps import make_decode_step, make_prefill_step, quantize_serving_params
@@ -130,14 +133,6 @@ class _Running:
         return len(self.tokens) >= self.req.gen
 
 
-def _host_nbytes(*trees) -> int:
-    """Bytes of the host (numpy) arrays in ``trees`` as a jitted call
-    copies them to the device (in JAX's canonical dtypes)."""
-    return sum(x.size * jax.dtypes.canonicalize_dtype(x.dtype).itemsize
-               for t in trees for x in jax.tree_util.tree_leaves(t)
-               if isinstance(x, (np.ndarray, np.generic)))
-
-
 def _priority(run: _Running):
     """Eviction order: latest arrival (then highest rid) goes first —
     the streams that have waited longest keep their pages."""
@@ -157,9 +152,15 @@ class Engine:
         # the guard turns on pool checksums; without one the engine takes
         # none of the guard paths and behaves exactly as before.
         self.guard = guard
+        # host spans and counters (runtime/spans.py, docs/SERVING.md
+        # §Spans and counters); recorded in memory only while started.
+        # The pool counts the bytes it moves in the same recorder.
+        self.spans = Recorder()
         self.pool = QPool(cfg, policy, page_size=ecfg.page_size,
                           n_pages=ecfg.n_pages, max_len=ecfg.max_len,
-                          src_len=src_len, integrity=guard is not None)
+                          src_len=src_len, integrity=guard is not None,
+                          spans=self.spans)
+        self._index_counted = 0     # pool.index_bytes already counted
         if params is None:
             # model load, exactly as serve.py: init from the seed key,
             # weights quantized once (the deployment contract) when the
@@ -231,9 +232,6 @@ class Engine:
         self.shed: Dict[int, str] = {}
         self.n_retries = 0
         self.eff_max_batch = ecfg.max_batch
-        # host spans and counters (runtime/spans.py, docs/SERVING.md
-        # §Spans and counters); recorded in memory only while started.
-        self.spans = Recorder()
         if guard is not None:
             guard.attach(self)
 
@@ -304,11 +302,11 @@ class Engine:
         admission and guard lane recovery (both must hit the same program
         with the same key for the bitwise invariant).  The batch goes in
         as host arrays, so a new prompt length compiles the prefill
-        program and nothing else."""
+        program and nothing else; the cache stays on the device."""
         batch = {"tokens": np.asarray(req.prompt, np.int32)[None]}
         for name, arr in (req.extras or {}).items():
             batch[name] = np.asarray(arr)[None]
-        self.spans.count("prefill.h2d_bytes", _host_nbytes(batch))
+        self.spans.count("prefill.h2d_bytes", host_nbytes(batch))
         return self._prefill(self.params, batch, self._prefill_key(req))
 
     def _do_prefill(self, run: _Running) -> None:
@@ -320,9 +318,8 @@ class Engine:
         run.last_progress_step = self.clock
         self.ttft_steps[req.rid] = self.clock - req.arrival_step
         with sp.span("engine.prefill_write", rid=req.rid):
-            host = jax.tree_util.tree_map(np.asarray, cache)
-            sp.count("prefill.d2h_bytes", _host_nbytes(tok, host))
-            self.pool.write(req.rid, host, upto=len(req.prompt))
+            sp.count("prefill.d2h_bytes", host_nbytes(tok))
+            self.pool.write(req.rid, cache, upto=len(req.prompt))
             self._retire_if_done(run)
 
     def _is_spec(self, run: _Running) -> bool:
@@ -401,7 +398,8 @@ class Engine:
         The chain is deterministic in (prompt, tokens, keys) — and the
         speculative verify scan IS the sequential program — so the result
         is bitwise identical to the cache the lane held before the fault,
-        for the KV families and the recurrent state slots alike."""
+        for the KV families and the recurrent state slots alike.  The
+        cache stays on the device."""
         req = run.req
         cache, _ = self._prefill_call(req)
         for i in range(run.n_decoded):
@@ -409,7 +407,7 @@ class Engine:
             _, cache = self._decode1(self.params, cache, tok,
                                      jnp.int32(len(req.prompt) + i),
                                      self._decode_key(req, i))
-        return jax.tree_util.tree_map(np.asarray, cache)
+        return cache
 
     def _recover_lane(self, rid: int, reason: str,
                       quarantine_pid: Optional[int] = None) -> None:
@@ -453,18 +451,17 @@ class Engine:
             self._decode_spec(spec)
 
     def _gather_lanes(self, lanes: List[_Running]):
-        """Each lane's contiguous cache out of the pool and its last
-        token; under vmap padded to ``max_batch`` with zero-cache lanes
-        and stacked (host numpy).  Returns (caches, tokens, pad)."""
-        caches = [self.pool.gather(r.req.rid) for r in lanes]
+        """Each lane's contiguous cache, gathered from the pool on the
+        device, and its last token; under vmap padded to ``max_batch``
+        with lanes read from the zero page and stacked.  Returns
+        (caches, tokens, pad)."""
+        rids = [r.req.rid for r in lanes]
         toks = [np.asarray(r.tokens[-1], np.int32) for r in lanes]
         if self.ecfg.max_batch == 1:
-            return caches[0], toks[0], 0
+            return self.pool.gather_lane(rids[0]), toks[0], 0
         pad = self.ecfg.max_batch - len(lanes)
-        caches += [self.pool.empty_cache()] * pad
-        vcache = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *caches)
         vtok = np.stack(toks + [np.zeros(1, np.int32)] * pad)
-        return vcache, vtok, pad
+        return self.pool.gather_lanes(rids, pad), vtok, pad
 
     def _lane_keys(self, keys, pad: int):
         """The lanes' raw decode keys on the host, padded with zeros."""
@@ -472,13 +469,6 @@ class Engine:
             [np.asarray(jax.random.key_data(k)) for k in keys]
             + [np.zeros_like(np.asarray(jax.random.key_data(
                 jax.random.key(0))))] * pad)
-
-    def _fetch_lanes(self, vcaches, n: int):
-        """The first ``n`` lanes' caches back from the device."""
-        out = [jax.tree_util.tree_map(lambda a, j=j: np.asarray(a[j]),
-                                      vcaches) for j in range(n)]
-        self.spans.count("engine.d2h_bytes", _host_nbytes(out))
-        return out
 
     def _decode_plain(self, lanes: List[_Running]) -> None:
         sp = self.spans
@@ -493,45 +483,34 @@ class Engine:
             with sp.span("engine.keys"):
                 key = self._decode_key(run.req, run.n_decoded)
             with sp.span("engine.decode"):
-                sp.count("engine.h2d_bytes", _host_nbytes(vcache, vtok, vpos))
+                sp.count("engine.h2d_bytes", host_nbytes(vtok, vpos[0]))
                 logits, cache = self._decode1(self.params, vcache, vtok,
                                               vpos[0], key)
                 out_toks = [np.asarray(
                     jnp.argmax(logits, -1).astype(jnp.int32))]
-                sp.count("engine.d2h_bytes", _host_nbytes(out_toks))
-            with sp.span("engine.scatter"):
-                out_caches = [jax.tree_util.tree_map(np.asarray, cache)]
-                sp.count("engine.d2h_bytes", _host_nbytes(out_caches))
-                self._commit_plain(lanes, out_toks, out_caches)
-            return
-        with sp.span("engine.keys"):
-            vkey = self._lane_keys(
-                [self._decode_key(r.req, r.n_decoded) for r in lanes], pad)
-        with sp.span("engine.decode"):
-            sp.count("engine.h2d_bytes",
-                     _host_nbytes(vcache, vtok, vpos, vkey))
-            vlogits, vcaches = self._decodeN(self.params, vcache, vtok,
-                                             vpos, vkey)
-            vout = np.asarray(jnp.argmax(vlogits, -1).astype(jnp.int32))
-            sp.count("engine.d2h_bytes", vout.nbytes)
+                sp.count("engine.d2h_bytes", host_nbytes(out_toks))
+        else:
+            with sp.span("engine.keys"):
+                vkey = self._lane_keys(
+                    [self._decode_key(r.req, r.n_decoded) for r in lanes],
+                    pad)
+            with sp.span("engine.decode"):
+                sp.count("engine.h2d_bytes", host_nbytes(vtok, vpos, vkey))
+                vlogits, cache = self._decodeN(self.params, vcache, vtok,
+                                               vpos, vkey)
+                vout = np.asarray(jnp.argmax(vlogits, -1).astype(jnp.int32))
+                sp.count("engine.d2h_bytes", vout.nbytes)
+                out_toks = [vout[j] for j in range(len(lanes))]
         with sp.span("engine.scatter"):
-            out_caches = self._fetch_lanes(vcaches, len(lanes))
-            self._commit_plain(lanes, [vout[j] for j in range(len(lanes))],
-                               out_caches)
-            # the step's buffers are released inside the span: freeing
-            # some hundreds of MB of host arrays can take milliseconds
-            del vcache, vcaches, out_caches
-
-    def _commit_plain(self, lanes, out_toks, out_caches) -> None:
-        for run, tok, host in zip(lanes, out_toks, out_caches):
-            block = run.pos // self.pool.page_size
-            self.pool.write(run.req.rid, host,
-                            block=block if self.pool.has_paged else None)
-            self.pool.set_length(run.req.rid, run.pos + 1)
-            run.n_decoded += 1
-            run.tokens.append(tok)
-            run.last_progress_step = self.clock
-            self._retire_if_done(run)
+            page = self.pool.page_size
+            self.pool.write_lanes([r.req.rid for r in lanes], cache,
+                                  [[r.pos // page] for r in lanes], pad)
+            for run, tok in zip(lanes, out_toks):
+                self.pool.set_length(run.req.rid, run.pos + 1)
+                run.n_decoded += 1
+                run.tokens.append(tok)
+                run.last_progress_step = self.clock
+                self._retire_if_done(run)
 
     def _decode_spec(self, lanes: List[_Running]) -> None:
         """One speculative round per lane: draft k, verify, commit the
@@ -558,48 +537,45 @@ class Engine:
                 key = jax.random.key(run.req.seed)
             with sp.span("engine.decode"):
                 sp.count("engine.h2d_bytes",
-                         _host_nbytes(vcache, vtok, vpos, vi0, vmc))
+                         host_nbytes(vtok, vpos[0], vi0[0], vmc[0]))
                 targets, commit, cache = self._spec1(
                     self.params, self._draft_params, vcache, vtok, vpos[0],
                     vi0[0], key, vmc[0])
-                targets, commit = np.asarray(targets), np.asarray(commit)
-                sp.count("engine.d2h_bytes", _host_nbytes(targets, commit))
-            with sp.span("engine.scatter"):
-                host = jax.tree_util.tree_map(np.asarray, cache)
-                sp.count("engine.d2h_bytes", _host_nbytes(host))
-                self._commit_spec(lanes, mcs, [(targets, int(commit[0]),
-                                                host)])
-            return
-        with sp.span("engine.keys"):
-            vkey = self._lane_keys(
-                [jax.random.key(r.req.seed) for r in lanes], pad)
-        with sp.span("engine.decode"):
-            sp.count("engine.h2d_bytes",
-                     _host_nbytes(vcache, vtok, vpos, vi0, vkey, vmc))
-            vtargets, vcommit, vcaches = self._specN(
-                self.params, self._draft_params, vcache, vtok, vpos, vi0,
-                vkey, vmc)
-            vtargets = np.asarray(vtargets)
-            vcommit = np.asarray(vcommit)
-            sp.count("engine.d2h_bytes", _host_nbytes(vtargets, vcommit))
+                vtargets, vcommit = np.asarray(targets)[None], \
+                    np.asarray(commit)[None]
+                sp.count("engine.d2h_bytes", host_nbytes(targets, commit))
+        else:
+            with sp.span("engine.keys"):
+                vkey = self._lane_keys(
+                    [jax.random.key(r.req.seed) for r in lanes], pad)
+            with sp.span("engine.decode"):
+                sp.count("engine.h2d_bytes",
+                         host_nbytes(vtok, vpos, vi0, vkey, vmc))
+                vtargets, vcommit, cache = self._specN(
+                    self.params, self._draft_params, vcache, vtok, vpos, vi0,
+                    vkey, vmc)
+                vtargets = np.asarray(vtargets)
+                vcommit = np.asarray(vcommit)
+                sp.count("engine.d2h_bytes", host_nbytes(vtargets, vcommit))
         with sp.span("engine.scatter"):
-            hosts = self._fetch_lanes(vcaches, len(lanes))
-            self._commit_spec(lanes, mcs, [
-                (vtargets[j], int(vcommit[j][0]), hosts[j])
-                for j in range(len(lanes))])
-            del vcache, vcaches, hosts
+            page = self.pool.page_size
+            commits = [int(vcommit[j][0]) for j in range(len(lanes))]
+            blocks = [list(range(r.pos // page, (r.pos + m - 1) // page + 1))
+                      for r, m in zip(lanes, commits)]
+            # a round of k drafts commits at most k + 1 rows: 1 + ceil(k /
+            # page) blocks
+            self.pool.write_lanes([r.req.rid for r in lanes], cache, blocks,
+                                  pad, 1 - (-self.ecfg.speculate // page))
+            self._commit_spec(lanes, mcs, [(vtargets[j], commits[j])
+                                           for j in range(len(lanes))])
 
     def _commit_spec(self, lanes, mcs, outs) -> None:
-        page = self.pool.page_size
-        for run, mc, (targets, m, host) in zip(lanes, mcs, outs):
+        for run, mc, (targets, m) in zip(lanes, mcs, outs):
             rid = run.req.rid
             p0 = run.pos
             for j in range(m):
                 run.tokens.append(targets[j])
             run.n_decoded += m
-            for b in range(p0 // page, (p0 + m - 1) // page + 1):
-                self.pool.write(rid, host,
-                                block=b if self.pool.has_paged else None)
             self.pool.set_length(rid, p0 + m)
             self.pool.trim_capacity(rid, p0 + m)
             self.spec_rounds += 1
@@ -612,9 +588,14 @@ class Engine:
             self._retire_if_done(run)
 
     def step(self) -> int:
-        """One simulated scheduler step; returns tokens emitted."""
+        """One simulated scheduler step; returns tokens emitted.  The page
+        tables and ids the pool sent count as the engine's own traffic."""
         with self.spans.span("engine.step"):
-            return self._step()
+            emitted = self._step()
+        self.spans.count("engine.h2d_bytes",
+                         self.pool.index_bytes - self._index_counted)
+        self._index_counted = self.pool.index_bytes
+        return emitted
 
     def _step(self) -> int:
         self.clock += 1
@@ -816,7 +797,7 @@ class Engine:
                 f"{dataclasses.asdict(self.ecfg)}")
         rm = meta["requests"]
         template = {
-            "pool": self.pool.snapshot_arrays(),
+            "pool": self.pool.snapshot_template(),
             "prompts": {rid: np.zeros(e["prompt_len"], np.int32)
                         for rid, e in rm.items()},
             "tokens": {rid: np.zeros(e["n_tokens"], np.int32)

@@ -168,24 +168,30 @@ def flip_pool_page_bits(pool, pid: int, seed: int,
     ``pid`` of a :class:`~repro.runtime.qpool.QPool` — the serving-side
     silent-corruption model (a DRAM fault in the block-paged qcache).
 
-    The flips mutate the pool storage in place and deliberately do NOT
-    touch the recorded per-page checksum, so ``scan_integrity`` must
-    catch the mismatch.  Deterministic in ``seed``."""
+    The flips rewrite the page through ``QPool.xor_page`` and
+    deliberately do NOT touch the recorded per-page checksum, so
+    ``scan_integrity`` must catch the mismatch.  Deterministic in
+    ``seed``."""
     role = pool._role.get(pid)
     store = pool._slots if role == "slot" else pool._paged
     if not store:
         store = pool._slots or pool._paged
     gen = np.random.Generator(np.random.Philox(seed))
     names = sorted(store)
+    masks = {}
     for _ in range(n_flips):
-        parts = store[names[int(gen.integers(0, len(names)))]]
+        name = names[int(gen.integers(0, len(names)))]
+        parts = store[name]
         # prefer mantissas; fall back to whatever integer part exists
         pname = "m" if "m" in parts else sorted(parts)[0]
-        arr = parts[pname][pid]
-        flat = arr.reshape(-1)
+        arr = parts[pname]
+        flat = masks.setdefault((name, pname), np.zeros(
+            int(np.prod(arr.shape[1:])), arr.dtype))
         i = int(gen.integers(0, flat.size))
         b = int(gen.integers(0, 8 * flat.dtype.itemsize - 1))
         flat[i] = flat[i] ^ np.asarray(1 << b, flat.dtype)
+    for (name, pname), flat in masks.items():
+        pool.xor_page(pid, name, pname, flat)
 
 
 # lanes currently stalled by injection: the engine skips a stalled lane's

@@ -138,7 +138,7 @@ def test_corrupt_free_page_is_quarantined_not_reissued(world):
     while not eng.results:
         eng.step()
     free_pid = next(p for p in eng.pool._free if p in eng.pool._sums)
-    eng.pool._paged["k"]["m"][free_pid] ^= 4
+    eng.pool.xor_page(free_pid, "k", "m", 4)
     out = eng.run()
     assert guard.event_counts() == {"page_quarantined": 1}
     assert eng.pool.quarantined_pages == 1

@@ -2,10 +2,11 @@
 
 The recorder is off by default; with it on, every ``Engine.step()`` is
 one ``engine.step`` span whose children are its phases, prefill spans
-carry the request id, the byte counters equal what the cache shapes
-give, and the emitted tokens are bitwise those of a run with it off.
-The same spans land in a ``jax.profiler`` trace.  One module fixture
-compiles the tiny engine's programs once; every engine shares them.
+carry the request id, the byte counters equal what the page tables,
+tokens and keys give (no cache crosses but on eviction), and the
+emitted tokens are bitwise those of a run with it off.  The same spans
+land in a ``jax.profiler`` trace.  One module fixture compiles the tiny
+engine's programs once; every engine shares them.
 """
 
 import dataclasses
@@ -108,27 +109,59 @@ def test_request_ids(runs):
     assert lanes == [1, 2, 2, 1]
 
 
-def test_byte_counters_from_the_cache_shapes(runs):
+def _cache_bytes():
     """One lane's cache at these shapes: K and V, each layers x kv heads
-    x max_len rows of head_dim int8 mantissas and one int32 exponent.
-    Every decode call copies max_batch such caches in and the live
-    lanes' back, with a token (int32), a position (int32) and a raw key
-    (2 x uint32) per lane in and a token per lane out."""
+    x max_len rows of head_dim int8 mantissas and one int32 exponent."""
     cfg = _tiny_cfg()
     hd = cfg.d_model // cfg.n_heads
     rows = cfg.n_layers * cfg.n_kv_heads * MAX_LEN
-    cache = 2 * (rows * hd + rows * 4)
-    assert cache == 1920
+    return 2 * (rows * hd + rows * 4)
+
+
+def test_byte_counters_from_the_cache_shapes(runs):
+    """No cache bytes cross on the decode path.  Every decode call sends
+    per lane a token (int32), a position (int32), a raw key (2 x
+    uint32), its page table (one int32 page id per block) and, for the
+    write-back, a block index and a page id (int32 each), and brings a
+    token per lane back.  A prefill's write into the pool sends its page
+    list (one id per block), each page allocation its page id, and the
+    prefill brings back its first token only.  With no eviction, no page
+    crosses."""
+    assert _cache_bytes() == 1920
     c = runs["record"]["counters"]
     calls = sum(s[0] == "engine.decode" for s in runs["record"]["spans"])
+    blocks = MAX_LEN // PAGE
     # each request decodes GEN - 1 tokens after its prefill's first
     assert c["engine.lanes"] == 3 * (GEN - 1)
     assert c["engine.pad_lanes"] == calls * LANES - c["engine.lanes"]
-    assert c["engine.h2d_bytes"] == calls * LANES * (cache + 4 + 4 + 8)
-    assert c["engine.d2h_bytes"] == c["engine.lanes"] * cache + \
-        calls * LANES * 4
+    assert runs["on"].n_preemptions == 0
+    assert c["engine.h2d_bytes"] == \
+        calls * LANES * (4 + 4 + 8 + 4 * blocks + 4 + 4) \
+        + 3 * 4 * blocks + 4 * runs["on"].pool.page_allocs
+    assert c["engine.d2h_bytes"] == calls * LANES * 4
     assert c["prefill.h2d_bytes"] == 3 * PROMPT_LEN * 4
-    assert c["prefill.d2h_bytes"] == 3 * (4 + cache)
+    assert c["prefill.d2h_bytes"] == 3 * 4
+    assert c.get("pool.d2h_bytes", 0) == 0
+    assert c.get("pool.h2d_bytes", 0) == 0
+
+
+def test_pool_counters_count_evictions(runs):
+    """A pool too small for the requests evicts: each eviction brings
+    one lane's cache to the host and its re-admission sends it back,
+    both counted under ``pool.*``; the tokens are those of the run with
+    room."""
+    eng = Engine(_tiny_cfg(), POLICY, EngineConfig(
+        max_len=MAX_LEN, page_size=2, n_pages=7, max_batch=LANES),
+        params=runs["off"].params, share_fns=runs["off"])
+    eng.spans.start()
+    out = eng.run(_requests(3))
+    c = eng.spans.stop()["counters"]
+    assert eng.n_preemptions > 0
+    assert c["pool.d2h_bytes"] == eng.n_preemptions * _cache_bytes()
+    assert c["pool.h2d_bytes"] == c["pool.d2h_bytes"]
+    assert out.keys() == runs["tokens_off"].keys()
+    for rid, toks in runs["tokens_off"].items():
+        assert np.array_equal(out[rid], toks)
 
 
 def test_compiles_count_under_the_span_that_compiled(runs):

@@ -1,7 +1,7 @@
 """Pool invariants for the block-paged qcache pool (runtime.qpool).
 
-Everything here is host-side numpy over ``cache_template`` trees filled
-with synthetic integer data — no model runs, no jit.  The engine-level
+Everything here runs the pool's own programs over ``cache_template``
+trees filled with synthetic integer data — no model runs.  The engine-level
 claims (golden pin, vmap-lane bit-identity, preemption resume) live in
 ``test_engine.py``; this file pins the allocator itself:
 
@@ -12,7 +12,11 @@ claims (golden pin, vmap-lane bit-identity, preemption resume) live in
 - a page-table gather is bit-identical to the contiguous cache it
   shreds, including the qcache zero (m=0, e=1) in unwritten tail blocks;
 - eviction + re-admission relocates pages as pure integer copies: ``==``
-  on mantissas AND exponents, with physically different page ids.
+  on mantissas AND exponents, with physically different page ids;
+- the device-side batch paths (``gather_lanes`` / ``write_lanes``) are
+  ``==`` to the per-lane host gather, the zero page stays the qcache
+  zero whatever padding lanes write, and the zero and scratch pages are
+  never handed out.
 """
 
 import dataclasses
@@ -360,7 +364,7 @@ def test_quarantine_free_page_and_live_page_rules():
     tail_pid = pool._seqs[0].blocks[-1]
     pool.trim_capacity(0, 4)                  # tail_pid back on free list
     # free pages keep their recorded checksum until realloc
-    pool._paged["k"]["m"][tail_pid] ^= 1
+    pool.xor_page(tail_pid, "k", "m", 1)
     assert pool.scan_integrity()["corrupt"] == [tail_pid]
     pool.quarantine_page(tail_pid)
     pool.quarantine_page(tail_pid)            # idempotent
@@ -396,3 +400,132 @@ def test_snapshot_restore_roundtrip_bitwise():
     assert fresh.scan_integrity()["corrupt"] == []
     fresh.release(0)
     assert fresh.accounting()["balanced"]
+
+
+# -- the device-resident pool: batched gather and write-back ---------------
+
+
+def _stack(trees):
+    """Per-lane batch-1 trees stacked along a new leading lane axis."""
+    out = {}
+    for name in trees[0]:
+        ps = [_parts(t[name]) for t in trees]
+        st = {pn: np.stack([p[pn] for p in ps]) for pn in ps[0]}
+        out[name] = BFP(st["m"], st["e"], trees[0][name].cfg) \
+            if isinstance(trees[0][name], BFP) else st["a"]
+    return out
+
+
+def _lane(tree, j):
+    """Lane ``j`` of a stacked tree, on the host."""
+    return {name: (BFP(np.asarray(leaf.m[j]), np.asarray(leaf.e[j]),
+                       leaf.cfg) if isinstance(leaf, BFP)
+                   else np.asarray(leaf[j]))
+            for name, leaf in tree.items()}
+
+
+def _pool_for(arch):
+    cfg = _tiny_cfg() if arch == "tiny" else get_smoke_config(arch)
+    return cfg, QPool(cfg, QC, page_size=4, n_pages=12, max_len=12,
+                      src_len=12)
+
+
+@pytest.mark.parametrize("arch", ["tiny", "rwkv6_3b", "recurrentgemma_2b"])
+def test_gather_lanes_equals_stacked_host_gather(arch):
+    """The device gather of a batch is ``==`` to the per-lane host gather
+    stacked — mantissas and exponents, part-written lanes whose tail
+    blocks are the qcache zero, a state-page family beside a paged one —
+    and every padding lane is ``empty_cache()``."""
+    cfg, pool = _pool_for(arch)
+    for rid, upto in ((0, 12), (1, 5), (2, 9)):
+        pool.admit(rid)
+        pool.ensure_capacity(rid, upto)
+        pool.write(rid, _random_cache(cfg, 12, seed=10 + rid, src_len=12),
+                   upto=upto)
+    got = pool.gather_lanes([2, 0, 1], pad=2)
+    _assert_tree_equal(
+        got, _stack([pool.gather(r) for r in (2, 0, 1)]
+                    + [pool.empty_cache()] * 2))
+    if pool.has_paged:
+        tail = np.asarray(got["k"].m[2][..., 8:, :])
+        assert tail.size and (tail == 0).all()
+        assert (np.asarray(got["k"].e[2][..., 8:, :]) == 1).all()
+
+
+@pytest.mark.parametrize("arch", ["tiny", "recurrentgemma_2b"])
+def test_write_lanes_then_gather_lanes_round_trip(arch):
+    """``write_lanes`` writes exactly each live lane's listed blocks and
+    its state leaves: gathered back, those blocks and the state equal the
+    written batch, and every other block is what it was."""
+    cfg, pool = _pool_for(arch)
+    before = {}
+    for rid in (0, 1):
+        pool.admit(rid)
+        pool.ensure_capacity(rid, 12)
+        pool.write(rid, _random_cache(cfg, 12, seed=20 + rid, src_len=12),
+                   upto=12)
+        before[rid] = pool.gather(rid)
+    batch = _stack([_random_cache(cfg, 12, seed=30 + j, src_len=12)
+                    for j in range(3)])
+    blocks = [[1], [0, 2]]
+    pool.write_lanes([0, 1], batch, blocks, pad=1, width=2)
+    got = pool.gather_lanes([0, 1])
+    for j, rid in enumerate((0, 1)):
+        new, old, lane = _lane(got, j), before[rid], _lane(batch, j)
+        for name, spec in pool._spec.items():
+            pn, po, pl = _parts(new[name]), _parts(old[name]), \
+                _parts(lane[name])
+            for part in pn:
+                if spec.seq_axis is None:
+                    np.testing.assert_array_equal(pn[part], pl[part])
+                    continue
+                for b in range(3):
+                    idx = [slice(None)] * pn[part].ndim
+                    idx[spec.seq_axis] = slice(4 * b, 4 * b + 4)
+                    want = pl if b in blocks[j] else po
+                    np.testing.assert_array_equal(
+                        pn[part][tuple(idx)], want[part][tuple(idx)],
+                        err_msg=f"lane {rid} {name}.{part} block {b}")
+
+
+def test_zero_page_survives_padding_lane_writes():
+    """Padding lanes read the zero page and write the scratch page: after
+    a write-back whose padding lanes hold random data, a padding lane
+    still gathers as ``empty_cache()``, and so does a sequence's
+    unallocated tail."""
+    cfg, pool = _pool_for("tiny")
+    pool.admit(0)
+    pool.ensure_capacity(0, 4)
+    noise = _stack([_random_cache(cfg, 12, seed=40 + j) for j in range(3)])
+    pool.write_lanes([0], noise, [[0]], pad=2)
+    got = pool.gather_lanes([0], pad=2)
+    for j in (1, 2):
+        _assert_tree_equal(_lane(got, j), pool.empty_cache())
+    for name in ("k", "v"):
+        assert (np.asarray(got[name].m[0][..., 4:, :]) == 0).all()
+        assert (np.asarray(got[name].e[0][..., 4:, :]) == 1).all()
+
+
+def test_zero_and_scratch_pages_never_handed_out():
+    """The two extra physical pages sit past the usable ones and off the
+    free list: filling the pool hands out exactly pages 0..n_pages-1,
+    and accounting and occupancy count those alone."""
+    cfg, pool = _pool_for("tiny")
+    assert (pool.zero_page, pool.scratch_page) == (12, 13)
+    for rid in range(4):
+        pool.admit(rid)
+        pool.ensure_capacity(rid, 12)
+    handed = sorted(p for rid in range(4) for p in pool._seqs[rid].blocks)
+    assert handed == list(range(12))
+    occ = pool.occupancy()
+    assert occ["n_pages"] == 12 and occ["live_pages"] == 12
+    assert occ["free_pages"] == 0 and occ["occupancy"] == 1.0
+    pool.admit(4)
+    with pytest.raises(PoolExhausted):
+        pool.ensure_capacity(4, 1)
+    for rid in range(5):
+        pool.release(rid)
+    acct = pool.accounting()
+    assert acct["balanced"] and acct["live_pages"] == 0
+    assert acct["page_allocs"] == acct["page_frees"] == 12
+    assert pool.free_pages == 12
