@@ -227,7 +227,7 @@ class PhaseTick:
         finally:
             shutil.rmtree(sl.logdir, ignore_errors=True)
         from bench import spec
-        families = spec.read_json("kernel_names.json")["families"]
+        families = spec.kernel_families()
         bench_spans = [s for s in spans if s[0].startswith("bench.")]
         reduced = trace.reduce_events(devices, bench_spans, families)
         win = [(s, e) for n, s, e, _ in bench_spans if n == trace.WINDOW_SPAN]

@@ -227,7 +227,7 @@ class TraceSlice:
             devices, spans = trace.read_xspace(trace.find_xspace(self.logdir))
         finally:
             shutil.rmtree(self.logdir, ignore_errors=True)
-        families = spec.read_json("kernel_names.json")["families"]
+        families = spec.kernel_families()
         out = trace.reduce_events(devices, spans, families)
         for line in trace.dump_op_names(devices, limit=25):
             _log(f"[trace] {line}")

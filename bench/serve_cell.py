@@ -2,7 +2,9 @@
 
 The engine is built as ``launch/serve.serve_engine`` builds it (the int8
 policy with quantize-once weights and the quantized cache, over
-``runtime/qpool.QPool``), on weights the benchmark makes from the seed.
+``runtime/qpool.QPool``), on weights the benchmark makes from the seed
+by the serving loader of the configuration's architecture
+(``bench/archs/<model_type>.py``).
 Set-up serves one short request per prompt bucket of the mix, which
 compiles the prefill program of each bucket (``Engine`` compiles one per
 prompt length) and the batched decode program.
@@ -18,8 +20,9 @@ wait so far.
 until ``check_requests`` requests have finished (for at most
 ``DRAIN_S``).  A sample of the finished requests drawn from the seed, the
 one with the most output tokens always in it, goes through the
-reference's full forward over prompt and served tokens.  ``token_gap`` is the widest gap by which a served token's
-reference logit lies below the reference's best logit at its position.
+architecture's reference, a full forward over prompt and served tokens.
+``token_gap`` is the widest gap by which a served token's reference logit
+lies below the reference's best logit at its position.
 """
 
 from __future__ import annotations
@@ -31,11 +34,9 @@ import types
 from typing import Dict, List
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from . import model, traffic
-from .reference import dense
+from . import model, spec, traffic
 
 WARM_RID = 1 << 30
 # the longest the engine steps on after the window for the check's sample
@@ -64,12 +65,12 @@ class ServeRun:
     def __init__(self, cell, seed: int, rate: float = 0.0,
                  share: "ServeRun" = None):
         from repro.launch.engine import EngineConfig
-        from repro.launch.steps import quantize_serving_params
         self.cell, self.seed = cell, seed
         self.conf, self.mix = cell.config, dict(cell.traffic)
         if rate:
             self.mix["rate_per_s"] = rate
-        self.cfg = model.arch_config(self.conf)
+        self.arch = spec.arch(self.conf["model_type"], cell.root)
+        self.cfg = self.arch.arch_config(self.conf)
         self.policy = serving_policy()
         m = self.mix
         max_len = int(m["max_len"])
@@ -79,11 +80,8 @@ class ServeRun:
                                  n_pages=lanes * (max_len // page) + 1,
                                  max_batch=lanes)
         self.key = model.seed_key(seed)
-        conf, cfg, policy = self.conf, self.cfg, self.policy
-        self._load = share._load if share else jax.jit(
-            lambda k: quantize_serving_params(
-                model.init_weights(k, conf), cfg, policy,
-                jax.random.fold_in(k, 0x9E)))
+        self._load = share._load if share else self.arch.serving_loader(
+            self.conf, self.cfg, self.policy)
         self.fns = share.fns if share else None
         self.engine = None
         self.arrivals: List[traffic.Arrival] = []
@@ -106,9 +104,9 @@ class ServeRun:
         return bool(e._pending or e._waiting or e._preempted or e._running)
 
     def setup(self) -> None:
-        """Weights from the seed in one jitted call, then one request per
-        prompt bucket (each compiles its prefill) through the batched
-        decode program."""
+        """Weights from the seed by the architecture's loader, then one
+        request per prompt bucket (each compiles its prefill) through the
+        batched decode program."""
         from repro.launch.engine import Request
         params = self._load(self.key)
         self.engine = make_engine(self.cfg, self.policy, self.ecfg, params,
@@ -255,29 +253,29 @@ class ServeRun:
         seqs = self.sequences()
         if not seqs:
             return {"token_gap": {"value": math.inf, "requests": 0}}
-        gap, served = token_gaps(self.conf, self.key, seqs,
+        gap, served = token_gaps(self.arch, self.conf, self.key, seqs,
                                  int(self.mix["max_len"]))
         return {"token_gap": {"value": gap, "requests": len(seqs),
                               "tokens": served}}
 
 
-def token_gaps(conf: dict, key, seqs, length: int, pick=None):
+def token_gaps(arch, conf: dict, key, seqs, length: int, pick=None):
     """(widest gap, tokens checked): at each position that produced a
-    served token, the reference's best logit less its logit of that token.
+    served token, the best logit of the architecture's reference less its
+    logit of that token.
     ``pick(prompt, served)``, where given, names the token to judge at
     each of those positions instead of the served one (the control reads
     its own first choices so).  Every sequence is padded at its end to
     ``length``, so one program serves them all (the attention is causal,
     so the padding changes no logit that is read)."""
     with jax.default_matmul_precision("highest"):
-        params = jax.jit(lambda k: model.init_weights(k, conf))(key)
-        fwd = jax.jit(lambda p, t: dense.logits(p, t, conf)[0])
+        logits = arch.reference(key, conf)
         worst, served = 0.0, 0
         for prompt, toks in seqs:
             seq = np.zeros(length, np.int32)
             seq[:len(prompt) + len(toks) - 1] = np.concatenate(
                 [prompt, toks[:-1]])
-            lg = np.asarray(fwd(params, jnp.asarray(seq)[None]))
+            lg = np.asarray(logits(seq))
             rows = lg[len(prompt) - 1:len(prompt) - 1 + len(toks)]
             judged = toks if pick is None else pick(prompt, toks)
             best = rows.max(axis=-1)

@@ -62,15 +62,12 @@ def _float32_grad_norms(cell, run):
     import jax.numpy as jnp
     from repro.core.policy import FLOAT32
     from repro.models import transformer
-    from bench import model
-    from bench.reference import dense
-    conf = cell.config
-    cfg = model.arch_config(conf)
+    conf, arch, cfg = cell.config, run.p.arch, run.p.cfg
     b = {k: jnp.asarray(v) for k, v in run.ds.batch_for_step(0).items()}
     grads = jax.jit(jax.grad(lambda p: transformer.loss_fn(
         p, b, run.key, FLOAT32, cfg)))(
-        jax.jit(lambda k: model.init_weights(k, conf))(run.key))
-    return [float(x) for x in dense.leaf_norms(grads)]
+        jax.jit(lambda k: arch.init_weights(k, conf))(run.key))
+    return [float(x) for x in arch.leaf_norms(grads)]
 
 
 def study_noise(cell, args) -> None:
@@ -83,13 +80,13 @@ def study_noise(cell, args) -> None:
     import jax.numpy as jnp
     from repro.launch.train import POLICIES
     from repro.models import transformer
-    from bench import model
-    from bench.reference import dense
+    from bench import model, train_cell
     from bench.traffic import SyntheticLM
     conf = dict(cell.config)
     if args.layers:
         conf["num_hidden_layers"] = args.layers
-    cfg = model.arch_config(conf)
+    arch = train_cell.training_arch(conf, cell.root)
+    cfg = arch.arch_config(conf)
     policy = POLICIES[cell.traffic["policy"]]
     tr = cell.traffic
     for seed in args.seeds:
@@ -97,9 +94,9 @@ def study_noise(cell, args) -> None:
         b = {k: jnp.asarray(v) for k, v in SyntheticLM(
             cfg.vocab, tr["seq"], tr["batch"], seed=seed).batch_for_step(0)
             .items()}
-        params = jax.jit(lambda k: model.init_weights(k, conf))(key)
+        params = jax.jit(lambda k: arch.init_weights(k, conf))(key)
         with jax.default_matmul_precision("highest"):
-            ref = jax.jit(jax.grad(lambda p: dense.loss(p, b, conf)))(params)
+            ref = jax.jit(jax.grad(lambda p: arch.loss(p, b, conf)))(params)
         grad = jax.jit(jax.grad(lambda p, k: transformer.loss_fn(
             p, b, k, policy, cfg)))
         one = grad(params, jax.random.fold_in(key, 1))
@@ -152,15 +149,11 @@ def _control_pick(run, policy_name: str):
     import jax.numpy as jnp
     import numpy as np
     from repro.launch.train import POLICIES
-    from repro.launch.steps import quantize_serving_params
     from repro.models import transformer
-    from bench import model
     pol = dataclasses.replace(POLICIES[policy_name], qweights=True,
                               qcache=True)
-    conf, cfg = run.conf, run.cfg
-    params = jax.jit(lambda k: quantize_serving_params(
-        model.init_weights(k, conf), cfg, pol,
-        jax.random.fold_in(k, 0x9E)))(run.key)
+    cfg = run.cfg
+    params = run.arch.serving_loader(run.conf, cfg, pol)(run.key)
     length = int(run.mix["max_len"])
 
     @jax.jit
@@ -195,7 +188,7 @@ def study_serve(cell, args) -> None:
                               "ttft_p90_ms", "itl_p95_ms")}, **nums)
         if seed in args.control_seeds:
             pick = _control_pick(run, CONTROL_POLICY)
-            gap, n = serve_cell.token_gaps(run.conf, run.key,
+            gap, n = serve_cell.token_gaps(run.arch, run.conf, run.key,
                                            run.sequences(),
                                            int(run.mix["max_len"]), pick)
             _emit(kind="control", seed=seed, token_gap=gap, tokens=n)

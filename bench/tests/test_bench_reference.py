@@ -4,8 +4,7 @@ small size, on the CPU."""
 import jax
 import jax.numpy as jnp
 
-from bench import model
-from bench.reference import dense
+from bench import model, spec
 from bench.traffic import SyntheticLM
 
 SMALL = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
@@ -18,16 +17,20 @@ MINICPM = dict(SMALL, num_key_value_heads=4, vocab_size=251, name="m",
                rope_theta=1e4, attention_bias=False, tie_word_embeddings=True)
 
 
+def _arch(conf):
+    return spec.arch(conf["model_type"])
+
+
 def _setup(conf, seed=3):
     key = model.seed_key(seed)
-    params = model.init_weights(key, conf)
+    params = _arch(conf).init_weights(key, conf)
     b = SyntheticLM(conf["vocab_size"], 24, 3, seed=seed).batch_for_step(0)
     return key, params, {k: jnp.asarray(v) for k, v in b.items()}
 
 
 def _program_loss(conf, policy):
     from repro.models import transformer
-    cfg = model.arch_config(conf)
+    cfg = _arch(conf).arch_config(conf)
     return lambda p, b, k: transformer.loss_fn(p, b, k, policy, cfg)
 
 
@@ -42,16 +45,17 @@ def test_float32_path_matches_reference(conf=QWEN):
     from repro.core.policy import FLOAT32
     from repro.models import transformer
     key, params, batch = _setup(conf)
-    cfg = model.arch_config(conf)
+    arch = _arch(conf)
+    cfg = arch.arch_config(conf)
     with jax.default_matmul_precision("highest"):
         ref_l, ref_g = jax.value_and_grad(
-            lambda p: dense.loss(p, batch, conf))(params)
+            lambda p: arch.loss(p, batch, conf))(params)
         prog_l, prog_g = jax.value_and_grad(
             lambda p: _program_loss(conf, FLOAT32)(p, batch, key))(params)
         h, _, _ = transformer.forward_hidden(params, batch["tokens"], key,
                                              FLOAT32, cfg)
         prog_logits = h @ params["embed"].T
-        ref_logits = dense.logits(params, batch["tokens"], conf)
+        ref_logits = arch.logits(params, batch["tokens"], conf)
     assert abs(float(prog_l) - float(ref_l)) <= 1e-5 * abs(float(ref_l))
     assert _rel(prog_logits, ref_logits) <= 1e-5
     for name, a, b in zip(model.leaf_names(ref_g),
@@ -70,7 +74,7 @@ def test_int8_path_near_reference(conf=MINICPM):
     key, params, batch = _setup(conf)
     with jax.default_matmul_precision("highest"):
         ref_l, ref_g = jax.value_and_grad(
-            lambda p: dense.loss(p, batch, conf))(params)
+            lambda p: _arch(conf).loss(p, batch, conf))(params)
     prog_l, prog_g = jax.value_and_grad(
         lambda p: _program_loss(conf, PAPER_INT8)(p, batch, key))(params)
     assert abs(float(prog_l) - float(ref_l)) <= 1e-2 * abs(float(ref_l))

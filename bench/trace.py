@@ -8,9 +8,10 @@ that start with ``bench.``).  ``reduce_events`` needs only lists of
 
 Busy time is the union of a device's operation intervals, averaged over
 the devices; the window is the ``bench.window`` span.  An operation
-belongs to a kernel family where ``kernel_names.json`` matches its name
-or its text (the HLO op's long name and the like); the rest is XLA's own
-work.  An idle gap is a stretch of the window in which the device ran
+belongs to the first kernel family (``spec.kernel_families``:
+``kernel_names.json``, then ``bench/kernels/<family>.json``) whose
+pattern matches its own HLO name (not its text, which names the operands
+that fed it); the rest is XLA's own work.  An idle gap is a stretch of the window in which the device ran
 nothing, named by the host span that covers most of it.
 """
 

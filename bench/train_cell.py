@@ -1,5 +1,7 @@
 """Training cells: the program's integer train step, timed and checked.
 
+The configuration's architecture (``bench/archs/<model_type>.py``) gives
+the start weights and the reference; it has to have the training hooks.
 Set-up builds one object, the jitted train step of
 ``launch/steps.make_train_step`` with its state from
 ``core.integer_sgd_init``, placed on the 1x1 mesh as ``launch/train.train``
@@ -33,12 +35,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import model
-from .reference import dense
+from . import model, spec
 from .traffic import SyntheticLM
 
 CHECK_STEPS = 3
 QUIET_LEAF = 1e-3
+# what an architecture gives the training runner besides serving's parts
+TRAIN_HOOKS = ("init_weights", "loss", "sgd_readings", "leaf_norms")
+
+
+def training_arch(conf: dict, root=spec.ROOT):
+    """The configuration's architecture, which must have the training
+    hooks."""
+    arch = spec.arch(conf["model_type"], root)
+    missing = [h for h in TRAIN_HOOKS if not hasattr(arch, h)]
+    if missing:
+        raise NotImplementedError(
+            f"{conf['name']}: {arch.__file__} has no {', '.join(missing)}; "
+            f"the training runner needs {', '.join(TRAIN_HOOKS)}")
+    return arch
 
 
 def make_step(cfg, policy, hyper) -> Callable:
@@ -104,7 +119,8 @@ class Programs:
         from jax.sharding import NamedSharding
         tr = cell.traffic
         self.conf = conf = cell.config
-        self.cfg = model.arch_config(conf)
+        self.arch = arch = training_arch(conf, cell.root)
+        self.cfg = arch.arch_config(conf)
         policy = POLICIES[policy_name or tr["policy"]]
         if kernel_mode:
             policy = dataclasses.replace(policy, kernel_mode=kernel_mode)
@@ -120,7 +136,7 @@ class Programs:
 
         def start(k):
             from repro.core import integer_sgd_init
-            return integer_sgd_init(model.init_weights(k, conf), policy,
+            return integer_sgd_init(arch.init_weights(k, conf), policy,
                                     key=k)
 
         self.start = jax.jit(start, out_shardings=state_shardings(
@@ -129,7 +145,7 @@ class Programs:
         self.change = jax.jit(lambda m, k: _bfp_norms_diff(
             m, start(k).masters))
         self.names = model.leaf_names(
-            jax.eval_shape(lambda k: model.init_weights(k, conf),
+            jax.eval_shape(lambda k: arch.init_weights(k, conf),
                            jax.random.key(0)))
 
 
@@ -216,9 +232,9 @@ class TrainRun:
     def reference(self) -> dict:
         """The reference's readings of steps 0..2."""
         batches = [self.ds.batch_for_step(i) for i in range(CHECK_STEPS)]
-        conf = self.conf
-        losses, g0, change = dense.sgd_readings(
-            lambda k: model.init_weights(k, conf), self.key, batches, conf,
+        conf, arch = self.conf, self.p.arch
+        losses, g0, change = arch.sgd_readings(
+            lambda k: arch.init_weights(k, conf), self.key, batches, conf,
             self.p.lr, self.p.momentum)
         return {"losses": losses, "grad": np.asarray(g0),
                 "change": np.asarray(change)}
